@@ -51,4 +51,7 @@ def run(h: int = 512, n_moduli: int = 4):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run()

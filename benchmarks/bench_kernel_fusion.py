@@ -172,6 +172,9 @@ def run(m: int = 256, n: int = 256, k: int = 512, p: int = 251,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import argparse
 
     ap = argparse.ArgumentParser()

@@ -45,4 +45,7 @@ def run(s: int = 384):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run()

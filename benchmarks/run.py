@@ -14,6 +14,9 @@ def main() -> None:
     args = ap.parse_args()
 
     import repro  # noqa: F401 (x64 for the numeric core)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from . import (
         bench_accuracy,
@@ -41,10 +44,7 @@ def main() -> None:
     ]
     for name, fn in sections:
         print(f"# --- {name} ---", file=sys.stderr)
-        try:
-            fn()
-        except Exception as e:  # pragma: no cover
-            print(f"{name}/ERROR,0.0,{type(e).__name__}:{e}")
+        fn()  # a failed section fails the run
     print(f"# total {time.time() - t0:.1f}s", file=sys.stderr)
 
 

@@ -346,6 +346,7 @@ COLLECTIVE_PRIMS = frozenset(
     {
         "psum",
         "psum2",
+        "psum_invariant",  # psum under shard_map(check_vma=True)
         "pmax",
         "pmin",
         "pmean",

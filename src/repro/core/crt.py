@@ -27,6 +27,7 @@ import numpy as np
 from .expansion import dd_add, dd_mul_fp, two_prod, quick_two_sum
 from .moduli import CRTContext
 from .residues import num_limbs_for_bits, residues_from_quantized, sym_mod_int32, sym_mod_small
+from .scaling import exp2i
 
 _F64 = jnp.float64
 
@@ -138,7 +139,7 @@ def reconstruct(e_res: jnp.ndarray, ctx: CRTContext, method: str = "paper"):
 
 def inverse_scale(hi, lo, e_mu, e_nu, out_dtype):
     """C = diag(mu)^-1 C' diag(nu)^-1 — exact (powers of two)."""
-    inv = jnp.ldexp(jnp.asarray(1.0, _F64), -(e_mu[:, None] + e_nu[None, :]))
+    inv = exp2i(-(e_mu[:, None] + e_nu[None, :]))
     return ((hi * inv) + (lo * inv)).astype(out_dtype)
 
 

@@ -181,7 +181,7 @@ class ReferenceBackend:
 
     def cast(self, x, e, axis, ctx, n_limbs):
         """quantize by 2^e along `axis` and residue-decompose (steps IV/V-i/ii)."""
-        xq = quantize(x.astype(jnp.float64), scaling.exp2_vector(e), axis)
+        xq = quantize(x.astype(jnp.float64), scaling.exp2i(e), axis)
         return residues_from_quantized(xq, ctx, n_limbs)
 
     def residue_matmul(self, ares, bres, ctx):
@@ -404,7 +404,8 @@ def _blocked_pipeline_complex(
 ):
     """Complex twin of `_blocked_pipeline_real`; `bres_slice(sl)` yields the
     (brr, bri) residue pair for one output-column block.  The two-phase
-    psum hooks apply to the stacked CR/CI partials the same way."""
+    psum hooks apply to the stacked CR/CI partials the same way.  Returns
+    the planar (cr, ci) output."""
     rdt = plan.real_out_dtype
     psum_partial = getattr(backend, "psum_partial", None)
     slices = list(plan.n_block_slices(n))
@@ -420,19 +421,25 @@ def _blocked_pipeline_complex(
             out = backend.reconstruct_post_stack(
                 full, e_mu, e_nu[sl], ctx, plan.method, rdt
             )
-            blocks.append(jax.lax.complex(out[0], out[1]))
-        return (
-            blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=1)
-        )
+            blocks.append((out[0], out[1]))
+        return _concat_planar(blocks)
     blocks = []
     for sl in slices:
         brr, bri = bres_slice(sl)
         er, ei = _complex_product(backend, plan, arr, ari, brr, bri, ctx)
-        cr, ci = _reconstruct_pair(
-            backend, er, ei, e_mu, e_nu[sl], ctx, plan.method, rdt
+        blocks.append(
+            _reconstruct_pair(
+                backend, er, ei, e_mu, e_nu[sl], ctx, plan.method, rdt
+            )
         )
-        blocks.append(jax.lax.complex(cr, ci))
-    return blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=1)
+    return _concat_planar(blocks)
+
+
+def _concat_planar(blocks):
+    """[(cr, ci), ...] output-column blocks -> one planar (cr, ci) pair."""
+    if len(blocks) == 1:
+        return blocks[0]
+    return tuple(jnp.concatenate(part, axis=1) for part in zip(*blocks))
 
 
 # ------------------------------------------------------- fused megakernel
@@ -534,19 +541,21 @@ def _fused_complex_block(
 def _fused_pipeline_complex(
     plan, backend, ctx, e_mu, ar, ai, e_nu, b_slice, b_res_slice, n
 ):
-    """Complex pipeline on a megakernel backend: one launch per block."""
+    """Complex pipeline on a megakernel backend: one launch per block.
+    Returns the planar (cr, ci) output."""
     nl = plan.n_limbs
     rdt = plan.real_out_dtype
     blocks = []
     for sl in plan.n_block_slices(n):
         b_blk = None if b_res_slice is not None else b_slice(sl)
         b_res_blk = b_res_slice(sl) if b_res_slice is not None else None
-        cr, ci = _fused_complex_block(
-            backend, plan, ctx, e_mu, ar, ai, e_nu[sl], b_blk, b_res_blk,
-            nl, rdt,
+        blocks.append(
+            _fused_complex_block(
+                backend, plan, ctx, e_mu, ar, ai, e_nu[sl], b_blk, b_res_blk,
+                nl, rdt,
+            )
         )
-        blocks.append(jax.lax.complex(cr, ci))
-    return blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=1)
+    return _concat_planar(blocks)
 
 
 def _accu_combines(backend):
@@ -583,9 +592,18 @@ def _execute_real(plan, a, b, backend):
 
 
 def _execute_complex(plan, a, b, backend):
+    return jax.lax.complex(
+        *execute_plan_planar(
+            plan, jnp.real(a), jnp.imag(a), jnp.real(b), jnp.imag(b), backend
+        )
+    )
+
+
+def execute_plan_planar(plan: EmulationPlan, ar, ai, br, bi, backend=REFERENCE):
+    """One 2D complex emulated GEMM on planar operands: (ar + i ai) @
+    (br + i bi) -> the planar (cr, ci) output.  No complex array is formed,
+    so a c128 product never puts a c128 array on the device."""
     ctx = plan.ctx
-    ar, ai = jnp.real(a), jnp.imag(a)
-    br, bi = jnp.real(b), jnp.imag(b)
     if plan.mode == "fast":
         e_mu, e_nu = scaling.scale_fast_complex(ar, ai, br, bi, ctx)
     else:
@@ -595,13 +613,13 @@ def _execute_complex(plan, a, b, backend):
     if getattr(backend, "megakernel", False):
         return _fused_pipeline_complex(
             plan, backend, ctx, e_mu, ar, ai, e_nu,
-            lambda sl: (br[:, sl], bi[:, sl]), None, b.shape[1],
+            lambda sl: (br[:, sl], bi[:, sl]), None, br.shape[1],
         )
     arr, ari = _cast_pair(backend, ar, ai, e_mu, 0, ctx, nl)
     return _blocked_pipeline_complex(
         plan, backend, ctx, e_mu, arr, ari, e_nu,
         lambda sl: _cast_pair(backend, br[:, sl], bi[:, sl], e_nu[sl], 1, ctx, nl),
-        b.shape[1],
+        br.shape[1],
     )
 
 
@@ -610,6 +628,22 @@ def _execute_complex(plan, a, b, backend):
 )
 def _run_plan_2d(a, b, plan, backend):
     return execute_plan(plan, a, b, backend)
+
+
+@functools.partial(
+    jnp.vectorize, excluded=(4, 5),
+    signature="(m,k),(m,k),(k,n),(k,n)->(m,n),(m,n)",
+)
+def _run_plan_planar_2d(ar, ai, br, bi, plan, backend):
+    return execute_plan_planar(plan, ar, ai, br, bi, backend)
+
+
+def run_plan_planar(plan: EmulationPlan, ar, ai, br, bi, backend=REFERENCE):
+    """`run_plan` for a complex plan on planar operands -> (cr, ci)."""
+    runner = getattr(backend, "run_plan_planar", None)
+    if runner is not None:
+        return runner(plan, ar, ai, br, bi)
+    return _run_plan_planar_2d(ar, ai, br, bi, plan, backend)
 
 
 def run_plan(plan: EmulationPlan, a, b, backend=REFERENCE):
@@ -899,18 +933,18 @@ def _gemm_prepared_accu(prep, x, plan, backend):
             br_, bi_ = wr, wi
         if getattr(backend, "megakernel", False):
             # accu re-casts from raw anyway, so the fused prologue applies
-            return _fused_pipeline_complex(
+            return jax.lax.complex(*_fused_pipeline_complex(
                 plan, backend, ctx, e_mu, ar_, ai_, e_nu,
                 lambda sl: (br_[:, sl], bi_[:, sl]), None, br_.shape[1],
-            )
+            ))
         arr, ari = _cast_pair(backend, ar_, ai_, e_mu, 0, ctx, nl)
-        return _blocked_pipeline_complex(
+        return jax.lax.complex(*_blocked_pipeline_complex(
             plan, backend, ctx, e_mu, arr, ari, e_nu,
             lambda sl: _cast_pair(
                 backend, br_[:, sl], bi_[:, sl], e_nu[sl], 1, ctx, nl
             ),
             br_.shape[1],
-        )
+        ))
 
     xbar, e_xbar, x_nz = scaling.accu_bound_real(x, other)
     pbar, e_pbar = prep.bound[0], prep.e_bound
@@ -1031,17 +1065,17 @@ def gemm_prepared(
         else:
             e_mu, e_nu = e_other, prep.e_scale
             if fused:
-                return _fused_pipeline_complex(
+                return jax.lax.complex(*_fused_pipeline_complex(
                     plan, backend, ctx, e_mu, xr, xi, e_nu, None,
                     lambda sl: tuple(r[..., sl] for r in prep.residues), n,
-                )
+                ))
             arr, ari = _cast_pair(backend, xr, xi, e_mu, 0, ctx, nl)
             bres_slice = lambda sl: tuple(  # noqa: E731
                 r[..., sl] for r in prep.residues
             )
-        return _blocked_pipeline_complex(
+        return jax.lax.complex(*_blocked_pipeline_complex(
             plan, backend, ctx, e_mu, arr, ari, e_nu, bres_slice, n
-        )
+        ))
 
     e_other = _solo_scale_real(x, ctx, other_side)
     if prep.side == "left":

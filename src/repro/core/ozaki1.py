@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from .intmul import int8_matmul
-from .scaling import exp2_vector, ilogb
+from .scaling import exp2i, ilogb
 
 SLICE_BITS = 7
 _F64 = jnp.float64
@@ -45,8 +45,8 @@ def _gemm_2d(a, b, n_slices, out_dtype):
     bmax = jnp.max(jnp.abs(b64), axis=0)
     e_mu = -(ilogb(jnp.where(amax > 0, amax, 1.0)) + 1)
     e_nu = -(ilogb(jnp.where(bmax > 0, bmax, 1.0)) + 1)
-    an = a64 * exp2_vector(e_mu)[:, None]   # rows in [0.5, 1)
-    bn = b64 * exp2_vector(e_nu)[None, :]
+    an = a64 * exp2i(e_mu)[:, None]   # rows in [0.5, 1)
+    bn = b64 * exp2i(e_nu)[None, :]
     asl = _slices(an, n_slices)
     bsl = _slices(bn, n_slices)
     acc = jnp.zeros(a.shape[:-1] + (b.shape[-1],), _F64)
@@ -57,7 +57,7 @@ def _gemm_2d(a, b, n_slices, out_dtype):
             j = s - i
             part = part + int8_matmul(asl[i], bsl[j]).astype(_F64)
         acc = acc + part * 2.0 ** (-SLICE_BITS * (s + 2))
-    inv = exp2_vector(-e_mu)[:, None] * exp2_vector(-e_nu)[None, :]
+    inv = exp2i(-e_mu)[:, None] * exp2i(-e_nu)[None, :]
     return (acc * inv).astype(out_dtype)
 
 

@@ -116,21 +116,39 @@ MI300X = HW("mi300x", 5300e9, 2615e12, 163e12, 163e12, ici_bw=45e10,
 
 HARDWARE = {h.name: h for h in (TPU_V5E, GH200, B200, RTX5080, MI300X)}
 
+#: the preset of each TPU, keyed by `jax.Device.device_kind`
+TPU_PRESETS = {"TPU v5 lite": TPU_V5E}
+
 
 def default_hw() -> HW:
     """The `HW` every ``hw=None`` model query prices against.
 
     The active calibration's *measured* hardware when a `repro.tune`
     calibration scope is live (`use_calibration` / `set_calibration` /
-    a `GemmPolicy(calibration=...)` pin), else the TPU v5e preset — the
-    historical default, so with no calibration present every 'auto'
-    decision is bitwise identical to the pre-calibration behaviour.
+    a `GemmPolicy(calibration=...)` pin).  Otherwise the preset of the
+    device JAX runs on: on a TPU the one for its `device_kind`
+    (`TPU_PRESETS`; an unknown TPU raises rather than price the wrong
+    chip), and on the CPU — where the kernels only run in interpret mode —
+    the TPU v5e preset, which stays the planning model there.
     """
     # lazy import: tune depends on this module, not the other way around
     from ..tune.cache import current_calibration
 
     cal = current_calibration()
-    return cal.hw if cal is not None else TPU_V5E
+    if cal is not None:
+        return cal.hw
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return TPU_V5E
+    if dev.platform == "tpu" and dev.device_kind in TPU_PRESETS:
+        return TPU_PRESETS[dev.device_kind]
+    raise ValueError(
+        f"no hardware preset for {dev.platform} device {dev.device_kind!r}; "
+        f"known TPUs: {sorted(TPU_PRESETS)} (or calibrate with "
+        "`python -m repro.tune`)"
+    )
 
 
 # ------------------------------------------------------------ engine terms

@@ -87,7 +87,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .executor import PreparedOperand, REFERENCE, gemm_prepared, run_plan
+from .executor import (
+    PreparedOperand,
+    REFERENCE,
+    gemm_prepared,
+    run_plan,
+    run_plan_planar,
+)
 from .plan import default_n_moduli, make_plan
 
 Backend = Literal[
@@ -571,6 +577,27 @@ def _emulated_bwd(policy, res, g):
 
 
 emulated_matmul.defvjp(_emulated_fwd, _emulated_bwd)
+
+
+def emulated_matmul_planar(xr, xi, wr, wi, policy: GemmPolicy):
+    """The complex emulated product on planar operands -> planar (cr, ci).
+
+    (xr + i xi) @ (wr + i wi) under a complex `policy`, with no complex
+    array anywhere in the program: a c128 product on a TPU, which cannot
+    hold a c128 array, runs this way.  Forward only (no custom VJP).
+    """
+    if not policy.is_complex:
+        raise ValueError(
+            f"planar products need a complex policy backend; got "
+            f"{policy.backend!r}"
+        )
+    plan = policy.plan_for(xr.shape[-2], xr.shape[-1], wr.shape[-1])
+    rt = jnp.finfo(policy.compute_dtype).dtype
+    with policy._calibration_scope():
+        return run_plan_planar(
+            plan, *(v.astype(rt) for v in (xr, xi, wr, wi)),
+            backend=policy.execution_backend(),
+        )
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))

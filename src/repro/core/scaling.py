@@ -8,7 +8,10 @@ Two modes, both for real and complex operands:
           (paper eqs. 13-14).  Tighter => fewer moduli for target accuracy.
 
 All scale factors are exact powers of two; we carry their integer exponents
-(the paper stores them as INT16) and materialize mu = 2^e via ldexp (exact).
+(the paper stores them as INT16) and materialize mu = 2^e exactly
+(`exp2i`).  Neither `frexp_exponent` nor `exp2i` reinterprets f64 bits:
+XLA's TPU x64 rewriter cannot lower an f64 <-> s64 bitcast, which is what
+`jnp.frexp` / `jnp.ldexp` emit.
 
 GPU->TPU adaptation: the paper bounds CUDA's __log2f error with
 delta = 0.5/(1-4u) in round-down/round-up mode; we use f64 log2 with an
@@ -17,7 +20,9 @@ explicit safety factor DELTA = 0.5*(1+2^-40) and floor() — same contract
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .intmul import int8_matmul
 from .moduli import CRTContext
@@ -26,10 +31,45 @@ DELTA = 0.5 * (1.0 + 2.0**-40)
 _F64 = jnp.float64
 
 
+@jax.jit  # one dispatch for eager callers; inlined under an outer jit
+def frexp_exponent(x: jnp.ndarray) -> jnp.ndarray:
+    """The int32 exponent of `np.frexp(x)`: x = f * 2^e with |f| in [0.5, 1).
+
+    Zero, inf and nan give 0, as in NumPy.  floor(log2 |x|) can be one off
+    next to a power of two; two exact comparisons with `exp2i` powers fix
+    it, so every normal f64 (the whole range [2^-1022, 2^1024), far outside
+    f32's) matches `np.frexp` bitwise.  f64 subnormals count as zero, as
+    XLA's arithmetic flushes them (not always its compares, hence the
+    explicit test).
+    """
+    x = jnp.abs(jnp.asarray(x, _F64))
+    normal = jnp.isfinite(x) & (x > 0) & (x >= np.finfo(np.float64).tiny)
+    safe = jnp.where(normal, x, 1.0)
+    e = jnp.floor(jnp.log2(safe)).astype(jnp.int32)
+    e = e - (safe < exp2i(e)).astype(jnp.int32)
+    e = e + (safe >= exp2i(e + 1)).astype(jnp.int32)
+    return jnp.where(normal, e + 1, 0)
+
+
 def ilogb(x: jnp.ndarray) -> jnp.ndarray:
-    """floor(log2 |x|) for x > 0, exact (frexp-based; paper uses ilogb())."""
-    _, e = jnp.frexp(x)
-    return (e - 1).astype(jnp.int32)
+    """floor(log2 |x|) for normal x != 0, exact (paper uses ilogb())."""
+    return frexp_exponent(x) - 1
+
+
+@jax.jit
+def exp2i(e: jnp.ndarray) -> jnp.ndarray:
+    """2^e in f64 for integer e: bitwise `np.ldexp(1.0, e)` wherever that
+    is a normal f64 (e in [-1022, 1023]), inf above and 0 below (XLA
+    flushes subnormal results).  A product of exact powers of two, one per
+    set bit of |e|, so no bits are reinterpreted."""
+    e = jnp.asarray(e, jnp.int32)
+    mag = jnp.abs(jnp.clip(e, -1100, 1100))
+    r = jnp.ones(e.shape, _F64)
+    for j in range(10):  # bits 2^0 .. 2^9 cover |e| <= 1023
+        k = 1 << j
+        step = jnp.where(e < 0, 2.0**-k, 2.0**k)
+        r = r * jnp.where((mag & k) != 0, step, 1.0)
+    return jnp.where(e > 1023, jnp.inf, jnp.where(e < -1022, 0.0, r))
 
 
 def _p_fast(ctx: CRTContext) -> float:
@@ -40,10 +80,6 @@ def _p_fast(ctx: CRTContext) -> float:
 def _p_accu(ctx: CRTContext) -> float:
     # P'_accu = log2(P-1)/2 - 0.5
     return ctx.log2_P / 2.0 - 0.5
-
-
-def _exp2i(e: jnp.ndarray) -> jnp.ndarray:
-    return jnp.ldexp(jnp.asarray(1.0, dtype=_F64), e.astype(jnp.int32))
 
 
 def _fast_exponent(
@@ -68,8 +104,8 @@ def scale_fast_real(a: jnp.ndarray, b: jnp.ndarray, ctx: CRTContext):
     b = b.astype(_F64)
     amax = jnp.max(jnp.abs(a), axis=1)
     bmax = jnp.max(jnp.abs(b), axis=0)
-    an = a * _exp2i(-ilogb(jnp.where(amax > 0, amax, 1.0)))[:, None]
-    bn = b * _exp2i(-ilogb(jnp.where(bmax > 0, bmax, 1.0)))[None, :]
+    an = a * exp2i(-ilogb(jnp.where(amax > 0, amax, 1.0)))[:, None]
+    bn = b * exp2i(-ilogb(jnp.where(bmax > 0, bmax, 1.0)))[None, :]
     e_mu = _fast_exponent(amax, jnp.sum(an * an, axis=1), ctx)
     e_nu = _fast_exponent(bmax, jnp.sum(bn * bn, axis=0), ctx)
     return e_mu, e_nu
@@ -82,8 +118,8 @@ def scale_fast_complex(ar, ai, br, bi, ctx: CRTContext):
     br, bi = br.astype(_F64), bi.astype(_F64)
     amax = jnp.maximum(jnp.max(jnp.abs(ar), axis=1), jnp.max(jnp.abs(ai), axis=1))
     bmax = jnp.maximum(jnp.max(jnp.abs(br), axis=0), jnp.max(jnp.abs(bi), axis=0))
-    sa = _exp2i(-ilogb(jnp.where(amax > 0, amax, 1.0)))[:, None]
-    sb = _exp2i(-ilogb(jnp.where(bmax > 0, bmax, 1.0)))[None, :]
+    sa = exp2i(-ilogb(jnp.where(amax > 0, amax, 1.0)))[:, None]
+    sb = exp2i(-ilogb(jnp.where(bmax > 0, bmax, 1.0)))[None, :]
     na = jnp.sum((ar * sa) ** 2 + (ai * sa) ** 2, axis=1)
     nb = jnp.sum((br * sb) ** 2 + (bi * sb) ** 2, axis=0)
     e_mu = _fast_exponent(amax, na, ctx)
@@ -95,7 +131,7 @@ def _bar_int8(x_abs: jnp.ndarray, e_bar: jnp.ndarray, axis: int) -> jnp.ndarray:
     """ceil(|x| * 2^e_bar) as int8 (<= 64; 7-bit upper-bound matrix)."""
     shape = [1] * x_abs.ndim
     shape[axis] = -1
-    v = jnp.ceil(x_abs * _exp2i(e_bar).reshape(shape))
+    v = jnp.ceil(x_abs * exp2i(e_bar).reshape(shape))
     return jnp.clip(v, 0, 127).astype(jnp.int8)
 
 
@@ -191,8 +227,3 @@ def scale_accurate_complex(
     return accu_exponents(
         cmax, e_abar, e_bbar, a_nz, b_nz, ctx, row_combine, col_combine
     )
-
-
-def exp2_vector(e: jnp.ndarray) -> jnp.ndarray:
-    """Materialize the power-of-two scale vector from integer exponents."""
-    return _exp2i(e)

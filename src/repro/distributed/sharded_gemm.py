@@ -47,16 +47,15 @@ from typing import Any
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh
 
-try:  # jax >= 0.6 exposes shard_map at the top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - version-dependent import
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from ..core import crt
-from ..core.executor import chunked_residue_matmul, execute_plan
+from ..core.executor import (
+    chunked_residue_matmul,
+    execute_plan,
+    execute_plan_planar,
+)
 from ..core.intmul import int8_matmul
 from ..core.moduli import CRTContext
 from ..core.residues import sym_mod_small
@@ -345,6 +344,17 @@ class ShardedBackend:
 
     def run_plan(self, plan, a, b):
         """Execute `plan` on (m, k) x (k, n) sharded over the mesh."""
+        return self._run(execute_plan, plan, a, b)
+
+    def run_plan_planar(self, plan, ar, ai, br, bi):
+        """`run_plan` for a complex plan on planar operands -> (cr, ci)."""
+        return self._run(execute_plan_planar, plan, ar, ai, br, bi)
+
+    def _run(self, execute, plan, *ops):
+        """shard_map `execute(plan, *A parts, *B parts, worker)` over the
+        mesh; the first half of `ops` are A's parts, the rest B's."""
+        half = len(ops) // 2
+        a, b = ops[0], ops[half]
         if getattr(a, "ndim", 0) != 2 or getattr(b, "ndim", 0) != 2:
             raise ValueError(
                 "sharded execution supports 2D operands; reshape leading "
@@ -354,15 +364,17 @@ class ShardedBackend:
         axes = self.resolve_axes(a.shape[0], b.shape[1])
         specs = residue_plane_specs(axes)
 
-        def body(al, bl):
+        def body(*local):
             worker = _ShardWorker(self.inner, plan.ctx, axes, self.mesh)
-            return execute_plan(plan, al, bl, worker)
+            return execute(plan, *local, worker)
 
-        fn = _shard_map(
+        # one output, or the planar (cr, ci) pair
+        out_specs = specs["out"] if half == 1 else (specs["out"],) * 2
+        fn = shard_map(
             body,
             mesh=self.mesh,
-            in_specs=(specs["a"], specs["b"]),
-            out_specs=specs["out"],
-            check_rep=False,
+            in_specs=(specs["a"],) * half + (specs["b"],) * half,
+            out_specs=out_specs,
+            check_vma=False,
         )
-        return fn(a, b)
+        return fn(*ops)

@@ -1,7 +1,7 @@
 """Shared helpers for the Ozaki-II Pallas TPU kernels.
 
 Everything here is exact f32/int32 arithmetic: the kernels never touch f64
-(TPU has none).  Values stay below 2^24 after the limb peel, where f32
+(TPU has none).  Values stay within 2^23 after the limb peel, where f32
 arithmetic on integers is error-free.
 
 Two flavours of the symmetric modular reduction coexist:
@@ -27,14 +27,35 @@ import numpy as np
 LIMB_BITS = 24
 LIMB = float(1 << LIMB_BITS)
 
+#: a constant block index for `BlockSpec` index maps.  With x64 enabled a
+#: literal 0 traces as s64, and Mosaic refuses a map that returns an s64.
+I0 = np.int32(0)
+
+
+#: scoped-VMEM limit of the fused megakernels.  Their prologue holds N f32
+#: residue tiles per operand part at once, which outgrows the TPU's 16 MiB
+#: default at the default blocks (the complex kernel needs ~17 MiB at N=7);
+#: a v5e core has 128 MiB of VMEM.
+FUSED_VMEM_LIMIT = 64 << 20
+
 
 def interpret_default() -> bool:
-    """Run kernels in interpret mode off-TPU (this container is CPU-only)."""
-    return jax.default_backend() != "tpu"
+    """Run kernels in interpret mode on the CPU and compiled on the TPU.
+
+    Any other backend raises: it would run neither the compiled kernels nor
+    the tested interpreter.
+    """
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"the Pallas kernels run on 'tpu' (compiled) or 'cpu' "
+            f"(interpret mode); the default backend is {backend!r}"
+        )
+    return backend == "cpu"
 
 
 def sym_mod_f32(v, p, half):
-    """Symmetric mod for f32 integer values |v| <~ 2^24 (exact, see core).
+    """Symmetric mod for f32 integer values |v| <= 2^23 (exact, see core).
 
     `p`/`half` may be Python floats (static modulus) or traced f32 scalars
     (dynamic modulus from scalar prefetch): the initial guess n = round(v/p)
@@ -90,30 +111,29 @@ def static_mod_params(p: int) -> tuple[float, float, float]:
     return float(p), float(half), float(m16)
 
 
-def residue_tiles_f32(x, s1, s2, *, moduli, n_limbs, scale_axis):
+def residue_tiles_f32(x, s1, s2, *, moduli, n_limbs):
     """Scale -> trunc -> limb-peel -> per-modulus canonical residues, in f32.
 
     The single implementation of Alg. 1 steps IV + V-i/ii shared by the
     standalone residue-cast kernel and the fused megakernel prologues: both
     run literally these ops, so their int8 planes are bitwise identical.
 
-    `x` is one (bm, bk) f32 tile; `s1*s2` the power-of-two scale factors
-    broadcast along rows (scale_axis=0) or columns (scale_axis=1).  Returns
+    `x` is one (bm, bk) f32 tile; `s1*s2` the power-of-two scale factors,
+    shaped (bm, 1) for a row scale or (1, bk) for a column scale.  Returns
     a list of N (bm, bk) f32 tiles, each the exact canonical symmetric
     residue (|r| <= (p-1)/2) ready for `.astype(jnp.int8)`.
     """
-    if scale_axis == 0:
-        scale = (s1 * s2)[:, None]
-    else:
-        scale = (s1 * s2)[None, :]
-    x = jnp.trunc(x * scale)  # exact: power-of-two scale, f32 trunc
+    x = jnp.trunc(x * (s1 * s2))  # exact: power-of-two scale, f32 trunc
 
-    # exact base-2^24 limb peel (DESIGN.md S2)
+    # exact base-2^24 limb peel (DESIGN.md S2), rounding to the nearest
+    # limb so every lower limb is <= 2^23 in magnitude: `sym_mod_f32` forms
+    # n*p, which rounds past 2^24 unless the compiler fuses it into an FMA
+    # (the CPU does, the TPU does not)
     limbs = []
     rem = x
     for i in reversed(range(1, n_limbs)):
         base = LIMB**i
-        hi = jnp.trunc(rem * (1.0 / base))  # 1/2^24k is a power of two: exact
+        hi = jnp.round(rem * (1.0 / base))  # 1/2^24k is a power of two: exact
         rem = rem - hi * base
         limbs.append(hi)
     limbs.append(rem)
@@ -146,16 +166,16 @@ def split_scale_exponent(e: np.ndarray | jnp.ndarray, bias: int = 0):
     """Split exponents e+bias into two f32-safe power-of-two factors.
 
     Returns (s1, s2) f32 with s1*s2 == 2^(e+bias) exactly, each factor's
-    exponent within f32 normal range for |e+bias| <= 252.
+    exponent within f32 normal range for |e+bias| <= 252.  The kernels
+    take them as 2-D (b, 1) / (1, b) blocks: a 1-D block of a scale vector
+    does not match the TPU's layout of that vector.
     """
+    from ..core.scaling import exp2i
+
     et = e + bias
     e1 = et // 2
     e2 = et - e1
-    one = jnp.float64(1.0)
-    return (
-        jnp.ldexp(one, e1).astype(jnp.float32),
-        jnp.ldexp(one, e2).astype(jnp.float32),
-    )
+    return exp2i(e1).astype(jnp.float32), exp2i(e2).astype(jnp.float32)
 
 
 # ------------------------------------------------- ragged-shape pad/slice

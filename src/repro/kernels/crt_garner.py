@@ -31,6 +31,7 @@ from jax.experimental import pallas as pl
 
 from ..core.moduli import CRTContext
 from .common import (
+    I0,
     block_and_padded,
     interpret_default,
     pad_dims,
@@ -96,8 +97,8 @@ def garner_tile(planes, rr, cc, *, ctx, out_dd):
 
 def _kernel(e_ref, r1_ref, r2_ref, c1_ref, c2_ref, out_ref, *, ctx, out_dd):
     planes = [e_ref[0, t, :, :].astype(jnp.float32) for t in range(ctx.n)]
-    rr = (r1_ref[...] * r2_ref[...])[:, None]
-    cc = (c1_ref[...] * c2_ref[...])[None, :]
+    rr = r1_ref[...] * r2_ref[...]
+    cc = c1_ref[...] * c2_ref[...]
     if out_dd:
         hi, lo = garner_tile(planes, rr, cc, ctx=ctx, out_dd=True)
         out_ref[0, 0, :, :] = hi
@@ -116,24 +117,26 @@ def _stacked_call(e_res, r1, r2, c1, c2, *, ctx, out_dd, bm, bn, interpret):
         else jax.ShapeDtypeStruct((s, m, n), jnp.float32)
     )
     out_spec = (
-        pl.BlockSpec((1, 2, bm, bn), lambda si, i, j: (si, 0, i, j))
+        pl.BlockSpec((1, 2, bm, bn), lambda si, i, j: (si, I0, i, j))
         if out_dd
         else pl.BlockSpec((1, bm, bn), lambda si, i, j: (si, i, j))
     )
+    row_spec = pl.BlockSpec((bm, 1), lambda si, i, j: (i, I0))
+    col_spec = pl.BlockSpec((1, bn), lambda si, i, j: (I0, j))
     return pl.pallas_call(
         functools.partial(_kernel, ctx=ctx, out_dd=out_dd),
         grid=(s, m // bm, n // bn),
         in_specs=[
-            pl.BlockSpec((1, ctx.n, bm, bn), lambda si, i, j: (si, 0, i, j)),
-            pl.BlockSpec((bm,), lambda si, i, j: (i,)),
-            pl.BlockSpec((bm,), lambda si, i, j: (i,)),
-            pl.BlockSpec((bn,), lambda si, i, j: (j,)),
-            pl.BlockSpec((bn,), lambda si, i, j: (j,)),
+            pl.BlockSpec((1, ctx.n, bm, bn), lambda si, i, j: (si, I0, i, j)),
+            row_spec,
+            row_spec,
+            col_spec,
+            col_spec,
         ],
         out_specs=out_spec,
         out_shape=out_shape,
         interpret=interpret,
-    )(e_res, r1, r2, c1, c2)
+    )(e_res, r1[:, None], r2[:, None], c1[None, :], c2[None, :])
 
 
 def crt_garner(

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import interpret_default
+from .common import I0, interpret_default
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
@@ -94,11 +94,11 @@ def flash_attention(
         ),
         grid=(b * h, s // bq, k_steps),
         in_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh // g, ki, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh // g, ki, 0)),
+            pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, I0)),
+            pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh // g, ki, I0)),
+            pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh // g, ki, I0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
+        out_specs=pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, I0)),
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),

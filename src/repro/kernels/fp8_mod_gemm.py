@@ -319,7 +319,7 @@ def fp8_karatsuba_mod_gemm_batched(
     bm, bn, bk = resolve_blocks("fp8", "complex", m, n, k, bm, bn, bk)
     bm, mp = block_and_padded(m, bm, align=128)
     bn, np_ = block_and_padded(n, bn, align=128)
-    bk, kp = block_and_padded(k, bk, align=32)
+    bk, kp = block_and_padded(k, bk, align=128)
     ar = pad_dims(ar, {1: mp, 2: kp})
     ai = pad_dims(ai, {1: mp, 2: kp})
     br = pad_dims(br, {1: kp, 2: np_})
@@ -372,7 +372,7 @@ def fp8_mod_gemm_batched(
     bm, bn, bk = resolve_blocks("fp8", "real", m, n, k, bm, bn, bk)
     bm, mp = block_and_padded(m, bm, align=128)
     bn, np_ = block_and_padded(n, bn, align=128)
-    bk, kp = block_and_padded(k, bk, align=32)
+    bk, kp = block_and_padded(k, bk, align=128)
     a = pad_dims(a, {1: mp, 2: kp})
     b = pad_dims(b, {1: kp, 2: np_})
     if carry is not None:

@@ -14,8 +14,9 @@ Grid: (N, m/bm, n/bn, k/bk) — modulus plane outermost, k innermost
 modulus is delivered via scalar prefetch (`PrefetchScalarGridSpec`): the
 moduli are a small int32 array argument, not a static Python `p`, and the
 epilogue derives (p, (p-1)/2, 2^16 mod p) from it in exact f32 arithmetic
-(`common.dyn_mod_params`).  MXU alignment: bm/bn multiples of 128, bk a
-multiple of 32 (int8 lane packing); non-block-divisible shapes are
+(`common.dyn_mod_params`).  MXU alignment: bm/bn/bk multiples of 128 (bk
+is the lane dimension of the A block, which the TPU tiles by 128) unless
+the axis fits one block; non-block-divisible shapes are
 zero-padded to the block grid and the output sliced back (zeros are
 residue-exact, see `common.pad_dims`).
 
@@ -35,6 +36,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .common import (
+    FUSED_VMEM_LIMIT,
+    I0,
     block_and_padded,
     dyn_mod_params,
     interpret_default,
@@ -144,7 +147,7 @@ def int8_mod_gemm_batched(
     bm, bn, bk = resolve_blocks("kernel", "real", m, n, k, bm, bn, bk)
     bm, mp = block_and_padded(m, bm, align=128)
     bn, np_ = block_and_padded(n, bn, align=128)
-    bk, kp = block_and_padded(k, bk, align=32)
+    bk, kp = block_and_padded(k, bk, align=128)
     a = pad_dims(a, {1: mp, 2: kp})
     b = pad_dims(b, {1: kp, 2: np_})
     if carry is not None:
@@ -191,7 +194,7 @@ def _fused_kernel(
     # --- prologue: in-kernel residue cast of the operand tiles ---
     a_tiles = residue_tiles_f32(
         a_ref[...], sa1_ref[...], sa2_ref[...],
-        moduli=ctx.moduli, n_limbs=n_limbs, scale_axis=0,
+        moduli=ctx.moduli, n_limbs=n_limbs,
     )
     if prepared:
         b_tiles = [b_ref[l] for l in range(n)]  # pre-cast int8 planes
@@ -200,7 +203,7 @@ def _fused_kernel(
             t.astype(jnp.int8)
             for t in residue_tiles_f32(
                 b_ref[...], sb1_ref[...], sb2_ref[...],
-                moduli=ctx.moduli, n_limbs=n_limbs, scale_axis=1,
+                moduli=ctx.moduli, n_limbs=n_limbs,
             )
         ]
 
@@ -231,8 +234,8 @@ def _fused_kernel(
         for l, p in enumerate(ctx.moduli):
             pf, half, m16 = static_mod_params(p)
             planes.append(sym_mod_int32_dyn(acc_ref[l], pf, half, m16))
-        rr = (r1_ref[...] * r2_ref[...])[:, None]
-        cc = (c1_ref[...] * c2_ref[...])[None, :]
+        rr = r1_ref[...] * r2_ref[...]
+        cc = c1_ref[...] * c2_ref[...]
         if out_dd:
             hi, lo = garner_tile(planes, rr, cc, ctx=ctx, out_dd=True)
             out_ref[0] = hi
@@ -250,39 +253,30 @@ def _fused_call(
     prepared = sb is None
     m = a.shape[0]
     n = (b.shape[-1])
-    in_specs = [
-        pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-        pl.BlockSpec((bm,), lambda i, j, kk: (i,)),
-        pl.BlockSpec((bm,), lambda i, j, kk: (i,)),
-    ]
-    operands = [a, sa1, sa2]
+    # scale vectors travel as (m, 1) rows / (1, n) columns (`split_scale_exponent`)
+    row_spec = pl.BlockSpec((bm, 1), lambda i, j, kk: (i, I0))
+    col_spec = pl.BlockSpec((1, bn), lambda i, j, kk: (I0, j))
+    in_specs = [pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)), row_spec, row_spec]
+    operands = [a, sa1[:, None], sa2[:, None]]
     if prepared:
         in_specs.append(
-            pl.BlockSpec((ctx.n, bk, bn), lambda i, j, kk: (0, kk, j))
+            pl.BlockSpec((ctx.n, bk, bn), lambda i, j, kk: (I0, kk, j))
         )
         operands.append(b)
     else:
-        in_specs.append(pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)))
-        operands.append(b)
         in_specs += [
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
+            pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)), col_spec, col_spec
         ]
-        operands += list(sb)
-    in_specs += [
-        pl.BlockSpec((bm,), lambda i, j, kk: (i,)),
-        pl.BlockSpec((bm,), lambda i, j, kk: (i,)),
-        pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
-        pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
-    ]
-    operands += [r1, r2, c1, c2]
+        operands += [b, sb[0][None, :], sb[1][None, :]]
+    in_specs += [row_spec, row_spec, col_spec, col_spec]
+    operands += [r1[:, None], r2[:, None], c1[None, :], c2[None, :]]
     out_shape = (
         jax.ShapeDtypeStruct((2, m, n), jnp.float32)
         if out_dd
         else jax.ShapeDtypeStruct((m, n), jnp.float32)
     )
     out_spec = (
-        pl.BlockSpec((2, bm, bn), lambda i, j, kk: (0, i, j))
+        pl.BlockSpec((2, bm, bn), lambda i, j, kk: (I0, i, j))
         if out_dd
         else pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j))
     )
@@ -296,6 +290,7 @@ def _fused_call(
         out_specs=out_spec,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((ctx.n, bm, bn), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=FUSED_VMEM_LIMIT),
         interpret=interpret,
     )(*operands)
 
@@ -341,7 +336,7 @@ def fused_mod_gemm(
     bm, bn, bk = resolve_blocks("fused", "real", m, n, k, bm, bn, bk)
     bm, mp = block_and_padded(m, bm, align=128)
     bn, np_ = block_and_padded(n, bn, align=128)
-    bk, kp = block_and_padded(k, bk, align=32)
+    bk, kp = block_and_padded(k, bk, align=128)
     a = pad_dims(a, {0: mp, 1: kp})
     e_mu = pad_dims(e_mu, {0: mp})
     e_nu = pad_dims(e_nu, {0: np_})

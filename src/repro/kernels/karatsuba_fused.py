@@ -20,7 +20,8 @@ Grid: (N, m/bm, n/bn, k/bk) — modulus outermost, k innermost, 3 int32 VMEM
 accumulators.  The per-plane modulus arrives via scalar prefetch as an
 int32 array (`PrefetchScalarGridSpec`); (p, (p-1)/2, 2^16 mod p) are
 derived in-kernel in exact f32 (`common.dyn_mod_params`).  Alignment: bm/bn
-multiples of 128, bk a multiple of 32; non-block-divisible shapes are
+and bk multiples of 128 (bk is the A block's lane dimension) unless the
+axis fits one block; non-block-divisible shapes are
 zero-padded to the block grid and sliced back (zero padding is
 residue-exact).  The optional `carry` pair (CR, CI residues of previous
 K-chunks) is folded into the epilogue mod, keeping chunked-K combines
@@ -36,6 +37,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .common import (
+    FUSED_VMEM_LIMIT,
+    I0,
     block_and_padded,
     dyn_mod_params,
     interpret_default,
@@ -174,7 +177,7 @@ def karatsuba_mod_gemm_batched(
     bm, bn, bk = resolve_blocks("kernel", "complex", m, n, k, bm, bn, bk)
     bm, mp = block_and_padded(m, bm, align=128)
     bn, np_ = block_and_padded(n, bn, align=128)
-    bk, kp = block_and_padded(k, bk, align=32)
+    bk, kp = block_and_padded(k, bk, align=128)
     ar = pad_dims(ar, {1: mp, 2: kp})
     ai = pad_dims(ai, {1: mp, 2: kp})
     br = pad_dims(br, {1: kp, 2: np_})
@@ -219,10 +222,10 @@ def _fused_kernel(
     # --- prologue: in-kernel residue casts (f32 canonical residue tiles) ---
     sa1, sa2 = sa1_ref[...], sa2_ref[...]
     art = residue_tiles_f32(
-        ar_ref[...], sa1, sa2, moduli=ctx.moduli, n_limbs=n_limbs, scale_axis=0
+        ar_ref[...], sa1, sa2, moduli=ctx.moduli, n_limbs=n_limbs
     )
     ait = residue_tiles_f32(
-        ai_ref[...], sa1, sa2, moduli=ctx.moduli, n_limbs=n_limbs, scale_axis=0
+        ai_ref[...], sa1, sa2, moduli=ctx.moduli, n_limbs=n_limbs
     )
     if prepared:
         brt = [brr_ref[l].astype(jnp.float32) for l in range(n)]
@@ -230,12 +233,10 @@ def _fused_kernel(
     else:
         sb1, sb2 = sb1_ref[...], sb2_ref[...]
         brt = residue_tiles_f32(
-            br_ref[...], sb1, sb2, moduli=ctx.moduli, n_limbs=n_limbs,
-            scale_axis=1,
+            br_ref[...], sb1, sb2, moduli=ctx.moduli, n_limbs=n_limbs
         )
         bit = residue_tiles_f32(
-            bi_ref[...], sb1, sb2, moduli=ctx.moduli, n_limbs=n_limbs,
-            scale_axis=1,
+            bi_ref[...], sb1, sb2, moduli=ctx.moduli, n_limbs=n_limbs
         )
 
     # --- the D/E/F Karatsuba triple per plane (sum operands in VMEM) ---
@@ -270,8 +271,8 @@ def _fused_kernel(
             df = sym_mod_int32_dyn(f_acc[l], pf, half, m16)
             cr_planes.append(sym_mod_f32(dr - de, pf, half))
             ci_planes.append(sym_mod_f32(df - dr - de, pf, half))
-        rr = (r1_ref[...] * r2_ref[...])[:, None]
-        cc = (c1_ref[...] * c2_ref[...])[None, :]
+        rr = r1_ref[...] * r2_ref[...]
+        cc = c1_ref[...] * c2_ref[...]
         if out_dd:
             hi, lo = garner_tile(cr_planes, rr, cc, ctx=ctx, out_dd=True)
             cr_ref[0], cr_ref[1] = hi, lo
@@ -291,28 +292,29 @@ def _fused_call(
     prepared = sb is None
     m = ar.shape[0]
     n = b_pair[0].shape[-1]
-    row_spec = pl.BlockSpec((bm,), lambda i, j, kk: (i,))
-    col_spec = pl.BlockSpec((bn,), lambda i, j, kk: (j,))
+    # scale vectors travel as (m, 1) rows / (1, n) columns (`split_scale_exponent`)
+    row_spec = pl.BlockSpec((bm, 1), lambda i, j, kk: (i, I0))
+    col_spec = pl.BlockSpec((1, bn), lambda i, j, kk: (I0, j))
     a_spec = pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk))
     in_specs = [a_spec, a_spec, row_spec, row_spec]
-    operands = [ar, ai, sa1, sa2]
+    operands = [ar, ai, sa1[:, None], sa2[:, None]]
     if prepared:
-        bp_spec = pl.BlockSpec((ctx.n, bk, bn), lambda i, j, kk: (0, kk, j))
+        bp_spec = pl.BlockSpec((ctx.n, bk, bn), lambda i, j, kk: (I0, kk, j))
         in_specs += [bp_spec, bp_spec]
         operands += list(b_pair)
     else:
         b_spec = pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j))
         in_specs += [b_spec, b_spec, col_spec, col_spec]
-        operands += list(b_pair) + list(sb)
+        operands += list(b_pair) + [sb[0][None, :], sb[1][None, :]]
     in_specs += [row_spec, row_spec, col_spec, col_spec]
-    operands += [r1, r2, c1, c2]
+    operands += [r1[:, None], r2[:, None], c1[None, :], c2[None, :]]
     one_shape = (
         jax.ShapeDtypeStruct((2, m, n), jnp.float32)
         if out_dd
         else jax.ShapeDtypeStruct((m, n), jnp.float32)
     )
     one_spec = (
-        pl.BlockSpec((2, bm, bn), lambda i, j, kk: (0, i, j))
+        pl.BlockSpec((2, bm, bn), lambda i, j, kk: (I0, i, j))
         if out_dd
         else pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j))
     )
@@ -330,6 +332,7 @@ def _fused_call(
             pltpu.VMEM((ctx.n, bm, bn), jnp.int32),
             pltpu.VMEM((ctx.n, bm, bn), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=FUSED_VMEM_LIMIT),
         interpret=interpret,
     )(*operands)
 
@@ -374,7 +377,7 @@ def fused_karatsuba_mod_gemm(
     bm, bn, bk = resolve_blocks("fused", "complex", m, n, k, bm, bn, bk)
     bm, mp = block_and_padded(m, bm, align=128)
     bn, np_ = block_and_padded(n, bn, align=128)
-    bk, kp = block_and_padded(k, bk, align=32)
+    bk, kp = block_and_padded(k, bk, align=128)
     ar = pad_dims(ar, {0: mp, 1: kp})
     ai = pad_dims(ai, {0: mp, 1: kp})
     e_mu = pad_dims(e_mu, {0: mp})

@@ -11,8 +11,8 @@ single launch — the complex pipeline stacks the real and imaginary parts of
 an operand so one operand costs one `pallas_call` regardless of dtype
 class.  2D inputs are treated as S=1 and squeezed on return.
 
-Block shapes: input (1, bm, bk) f32; scale factors (bm,) broadcast along
-rows (axis=0 operand) or (bk,) along columns (axis=1); output
+Block shapes: input (1, bm, bk) f32; scale factors (bm, 1) broadcast along
+rows (axis=0 operand) or (1, bk) along columns (axis=1); output
 (1, N, bm, bk) int8 — N is small and static, the whole stack of output
 tiles lives in VMEM (N * bm * bk bytes; 13 * 256 * 512 = 1.7 MiB).
 Non-block-divisible m/k are zero-padded to the block grid and sliced back
@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .common import (
+    I0,
     block_and_padded,
     interpret_default,
     pad_dims,
@@ -34,10 +35,9 @@ from .common import (
 )
 
 
-def _kernel(a_ref, s1_ref, s2_ref, out_ref, *, moduli, n_limbs, scale_axis):
+def _kernel(a_ref, s1_ref, s2_ref, out_ref, *, moduli, n_limbs):
     tiles = residue_tiles_f32(
-        a_ref[0], s1_ref[...], s2_ref[...],
-        moduli=moduli, n_limbs=n_limbs, scale_axis=scale_axis,
+        a_ref[0], s1_ref[...], s2_ref[...], moduli=moduli, n_limbs=n_limbs
     )
     for l in range(len(moduli)):
         out_ref[0, l, :, :] = tiles[l].astype(jnp.int8)
@@ -52,21 +52,22 @@ def _stacked_call(a, scale1, scale2, *, moduli, n_limbs, scale_axis, bm, bk,
     s, m, k = a.shape
     n = len(moduli)
 
-    def smap(si, i, j):
-        return (i,) if scale_axis == 0 else (j,)
-
+    if scale_axis == 0:
+        scale_spec = pl.BlockSpec((bm, 1), lambda si, i, j: (i, I0))
+        scale1, scale2 = scale1[:, None], scale2[:, None]
+    else:
+        scale_spec = pl.BlockSpec((1, bk), lambda si, i, j: (I0, j))
+        scale1, scale2 = scale1[None, :], scale2[None, :]
     grid = (s, m // bm, k // bk)
     return pl.pallas_call(
-        functools.partial(
-            _kernel, moduli=moduli, n_limbs=n_limbs, scale_axis=scale_axis
-        ),
+        functools.partial(_kernel, moduli=moduli, n_limbs=n_limbs),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bm, bk), lambda si, i, j: (si, i, j)),
-            pl.BlockSpec((bm if scale_axis == 0 else bk,), smap),
-            pl.BlockSpec((bm if scale_axis == 0 else bk,), smap),
+            scale_spec,
+            scale_spec,
         ],
-        out_specs=pl.BlockSpec((1, n, bm, bk), lambda si, i, j: (si, 0, i, j)),
+        out_specs=pl.BlockSpec((1, n, bm, bk), lambda si, i, j: (si, I0, i, j)),
         out_shape=jax.ShapeDtypeStruct((s, n, m, k), jnp.int8),
         interpret=interpret,
     )(a, scale1, scale2)

@@ -12,10 +12,25 @@ unchanged): the N int8 residue planes of every emulated GEMM shard over it,
 m/n shard over data/model as usual, and only the reconstructed output is
 psum-combined (see `distributed/sharded_gemm.py`).  With `residue=1` the
 mesh shapes are exactly the pre-existing 2- and 3-axis layouts.
+
+Every mesh here is built by `make_mesh`, whose axes are all Auto: the
+models place their activations with bare-`PartitionSpec`
+`with_sharding_constraint` calls and let the partitioner propagate the
+rest, which `jax.make_mesh`'s default Explicit axes refuse (the embedding
+gather at the top of the model raises a `ShardingTypeError`).
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """`jax.make_mesh` with every axis Auto (see the module docstring)."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False, residue: int = 1):
@@ -36,10 +51,10 @@ def make_production_mesh(*, multi_pod: bool = False, residue: int = 1):
             if multi_pod
             else ("data", "model", "residue")
         )
-        return jax.make_mesh(shape, axes)
+        return make_mesh(shape, axes)
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1, residue: int = 1):
@@ -54,7 +69,5 @@ def make_host_mesh(data: int = 1, model: int = 1, residue: int = 1):
     model = min(model, max(1, n // data))
     if residue > 1:
         residue = min(residue, max(1, n // (data * model)))
-        return jax.make_mesh(
-            (data, model, residue), ("data", "model", "residue")
-        )
-    return jax.make_mesh((data, model), ("data", "model"))
+        return make_mesh((data, model, residue), ("data", "model", "residue"))
+    return make_mesh((data, model), ("data", "model"))

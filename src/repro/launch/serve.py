@@ -3,7 +3,9 @@
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-32b \
         --batch 8 --prompt-len 64 --new-tokens 64 [--temperature 0.8] \
         [--backend ozaki2_f32] [--execution kernel] \
-        [--prepare] [--prepared-dir DIR]
+        [--prepare] [--prepared-dir DIR] [--full]
+
+--full serves the published configuration instead of the reduced one.
 
 An emulated --backend scopes the whole model onto that GemmPolicy via
 `repro.use_policy` around config lookup (the context-scoped drop-in path);
@@ -24,16 +26,23 @@ import jax.numpy as jnp
 import contextlib
 
 import repro
-from repro.configs import ARCHS, get_reduced
+from repro.configs import ARCHS, get_config, get_reduced
 from repro.core import GemmPolicy
 from repro.models import Model
 from repro.serve import ServeEngine
 from repro.tune.cli import add_calibration_args, apply_calibration_args
 
+from .compile_cache import enable_compile_cache
 
-def main():
+
+def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCHS, required=True)
+    ap.add_argument("--reduced", action="store_true", default=True,
+                    help="reduced config (the default)")
+    ap.add_argument("--full", dest="reduced", action="store_false",
+                    help="the published configuration")
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--new-tokens", type=int, default=64)
@@ -65,7 +74,7 @@ def main():
                          "fewest moduli provably meeting it; required for "
                          "--mode auto)")
     add_calibration_args(ap)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     apply_calibration_args(args)
     if args.mode == "auto" and args.rtol is None:
         ap.error("--mode auto needs an accuracy target: pass --rtol")
@@ -85,7 +94,7 @@ def main():
                        mesh=mesh, mode=args.mode, rtol=args.rtol)
         )
     with scope:
-        cfg = get_reduced(args.arch, **(
+        cfg = (get_reduced if args.reduced else get_config)(args.arch, **(
             {} if args.backend == "native" else {"dtype": "float32"}
         ))
     model = Model(cfg)

@@ -12,8 +12,9 @@ modulus-batched Pallas kernels, or the per-modulus parity path), `--mode` /
 `--formulation` / `--n-block` the paper's accuracy and Fig. 1 strategy knobs
 ('auto' consults the SIII-C perfmodel per shape).
 
-On this CPU container the mesh defaults to 1x1; on a real pod pass
---mesh 16x16 (the dry-run proves those configs compile for every arch).
+Without --mesh the run uses one device; on a pod pass --mesh 16x16 (the
+dry-run proves those configs compile for every arch).  Compiled programs
+persist in the compile cache (`repro.launch.compile_cache`).
 """
 from __future__ import annotations
 
@@ -31,13 +32,19 @@ from repro.optim import AdamWConfig
 from repro.train import TrainLoopConfig, train_loop
 from repro.tune.cli import add_calibration_args, apply_calibration_args
 
+from .compile_cache import enable_compile_cache
+from .mesh import make_host_mesh, make_mesh
+
 
 def parse_n_block(s: str):
     """CLI n_block: an integer or the literal 'auto' (perfmodel-driven)."""
     return "auto" if s == "auto" else int(s)
 
 
-def main():
+def main(argv=None) -> list[float]:
+    """Parse `argv` (default: the command line), train, and return the
+    per-step losses."""
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCHS, required=True)
     ap.add_argument("--reduced", action="store_true", default=True,
@@ -78,7 +85,7 @@ def main():
     ap.add_argument("--seq-shard", action="store_true")
     ap.add_argument("--vocab-chunk", type=int, default=None)
     add_calibration_args(ap)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     apply_calibration_args(args)
     if args.mode == "auto" and args.rtol is None:
         ap.error("--mode auto needs an accuracy target: pass --rtol")
@@ -87,16 +94,12 @@ def main():
     if args.mesh:
         d, m = map(int, args.mesh.split("x"))
         if args.residue > 1:
-            mesh = jax.make_mesh(
-                (d, m, args.residue), ("data", "model", "residue")
-            )
+            mesh = make_mesh((d, m, args.residue), ("data", "model", "residue"))
         else:
-            mesh = jax.make_mesh((d, m), ("data", "model"))
+            mesh = make_mesh((d, m), ("data", "model"))
     elif args.execution == "sharded":
         # sharded execution needs a mesh even on a single host: default to
         # every local device on the residue axis
-        from .mesh import make_host_mesh
-
         mesh = make_host_mesh(
             1, 1,
             residue=args.residue if args.residue > 1 else len(jax.devices()),
@@ -135,6 +138,7 @@ def main():
     _, hist = train_loop(model, data, loop, AdamWConfig(lr=args.lr, grad_clip=5.0),
                          mesh=mesh)
     print(f"[{args.arch}] loss {hist[0]:.4f} -> {hist[-1]:.4f}")
+    return hist
 
 
 if __name__ == "__main__":

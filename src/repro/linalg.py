@@ -52,6 +52,7 @@ import threading
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .core.executor import PreparedOperand
 from .core.policy import (
@@ -60,6 +61,7 @@ from .core.policy import (
     NATIVE,
     current_mesh,
     emulated_matmul,
+    emulated_matmul_planar,
     policy_matmul,
     prepare_weights,
     use_mesh,
@@ -79,6 +81,7 @@ __all__ = [
     "use_mesh",
     "use_policy",
     "zgemm",
+    "zgemm_planar",
 ]
 
 _STATE = threading.local()
@@ -242,10 +245,17 @@ def matmul_jit(x, w, *, policy: GemmPolicy | None = None):
     return _matmul_jit(x, w, policy=policy)
 
 
-def _blas(routine: str, dtype, x, w, policy: GemmPolicy | None):
+def _blas_policy(dtype, policy: GemmPolicy | None) -> GemmPolicy:
+    """`policy` (default: the ambient one) with the routine's backend."""
     base = current_policy() if policy is None else policy
+    return dataclasses.replace(
+        base, backend=BACKEND_FOR_DTYPE[jnp.dtype(dtype).name]
+    )
+
+
+def _blas(routine: str, dtype, x, w, policy: GemmPolicy | None):
     dt = jnp.dtype(dtype)
-    pol = dataclasses.replace(base, backend=BACKEND_FOR_DTYPE[dt.name])
+    pol = _blas_policy(dt, policy)
     if isinstance(w, PreparedOperand):
         if jnp.dtype(w.dtype) != dt:
             raise ValueError(
@@ -299,10 +309,76 @@ def zgemm(x, w, *, policy: GemmPolicy | None = None):
     """Emulated ZGEMM (paper SIII): complex128 compute — the headline
     routine on hardware with no native f64 (TPU v5e).
 
+    A TPU program cannot take or return a c128 array, so concrete operands
+    are split into planar f64 (re, im) parts on the host, the jitted
+    `zgemm_planar` program runs on those, and the c128 result is assembled
+    on the host: it comes back as a NumPy array.  Inside a caller's `jit`
+    (traced operands) and for prepared weights the product takes the
+    complex route, which only the CPU compiles; call `zgemm_planar` there.
+
     >>> import jax.numpy as jnp, repro
     >>> a = jnp.eye(2, dtype=jnp.complex128) * 2j
     >>> y = repro.linalg.zgemm(a, a)
     >>> (y.dtype.name, complex(y[0, 0]))
     ('complex128', (-4+0j))
     """
-    return _blas("zgemm", jnp.complex128, x, w, policy)
+    if isinstance(w, PreparedOperand) or any(
+        isinstance(v, jax.core.Tracer) for v in (x, w)
+    ):
+        return _blas("zgemm", jnp.complex128, x, w, policy)
+    pol = _blas_policy(jnp.complex128, policy)
+    x = np.asarray(x, np.complex128)
+    w = np.asarray(w, np.complex128)
+    if w.ndim == 2 and x.ndim >= 2 and pol.is_adaptive:
+        # what `policy_matmul` does for a 2D weight: probe the operands
+        from .core.accuracy import probe_operands
+
+        k = x.shape[-1]
+        pol = pol.resolve_adaptive(
+            x.size // k, k, w.shape[-1],
+            stats=probe_operands(x.reshape(-1, k), w),
+        )
+    if pol.mesh is None and pol.execution in ("sharded", "fused"):
+        # resolve the ambient mesh before jit, as `matmul_jit` does
+        pol = dataclasses.replace(pol, mesh=current_mesh())
+    cr, ci = _zgemm_planar_jit(x.real, x.imag, w.real, w.imag, policy=pol)
+    cr, ci = np.asarray(cr), np.asarray(ci)
+    out = np.empty(cr.shape, np.result_type(cr.dtype, np.complex64))
+    out.real, out.imag = cr, ci
+    return out
+
+
+def zgemm_planar(xr, xi, wr, wi, *, policy: GemmPolicy | None = None):
+    """ZGEMM on planar f64 operands: (xr + i xi) @ (wr + i wi) -> (cr, ci).
+
+    The form of `zgemm` that traces into a caller's `jit` on any backend:
+    no c128 array enters or leaves the program.  x parts: (..., m, k);
+    w parts: (k, n) or batched (..., k, n).  Every knob except the backend
+    comes from `policy` / the ambient scope, as for `zgemm`.  Forward only.
+
+    >>> import jax.numpy as jnp, repro
+    >>> eye = jnp.eye(2, dtype=jnp.float64)
+    >>> cr, ci = repro.linalg.zgemm_planar(0 * eye, 2 * eye, 0 * eye, 2 * eye)
+    >>> (cr.dtype.name, float(cr[0, 0]), float(ci[0, 0]))
+    ('float64', -4.0, 0.0)
+    """
+    pol = _blas_policy(jnp.complex128, policy)
+    if getattr(xr, "ndim", 0) < 2 or getattr(wr, "ndim", 0) < 2:
+        raise ValueError(
+            "zgemm_planar expects matrix operands (ndim >= 2); got shapes "
+            f"{getattr(xr, 'shape', None)} @ {getattr(wr, 'shape', None)}"
+        )
+    if pol.is_adaptive:
+        pol = pol.resolve_adaptive(xr.shape[-2], xr.shape[-1], wr.shape[-1])
+    if wr.ndim > 2 or xr.ndim == 2:
+        return emulated_matmul_planar(xr, xi, wr, wi, pol)
+    # (..., m, k) @ (k, n): fold the leading dims into rows, as policy_matmul
+    lead = xr.shape[:-1]
+    k = xr.shape[-1]
+    cr, ci = emulated_matmul_planar(
+        xr.reshape(-1, k), xi.reshape(-1, k), wr, wi, pol
+    )
+    return cr.reshape(lead + cr.shape[-1:]), ci.reshape(lead + ci.shape[-1:])
+
+
+_zgemm_planar_jit = jax.jit(zgemm_planar, static_argnames=("policy",))

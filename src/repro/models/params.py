@@ -9,6 +9,7 @@ Every layer describes its parameters once as a pytree of `ParamMeta`
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from typing import Any
 
 import jax
@@ -81,12 +82,16 @@ def _map_like(tree, fn, path=()):
 
 
 def materialize(abstract: Any, key: jax.Array) -> Any:
-    """Deterministic init: each leaf gets fold_in(key, hash(path))."""
+    """Deterministic init: each leaf gets fold_in(key, crc32(path)).
+
+    A checksum, not `hash()`: Python salts string hashes per process, so
+    the same seed would give different weights in every process.
+    """
 
     def init(path, meta):
         k = key
         for part in path:
-            k = jax.random.fold_in(k, abs(hash(part)) % (2**31))
+            k = jax.random.fold_in(k, zlib.crc32(part.encode()) % (2**31))
         return _init_one(meta, k)
 
     return _map_like(abstract, init)

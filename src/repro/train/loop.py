@@ -67,13 +67,16 @@ def train_loop(
             if batch_hook:
                 batch = batch_hook(batch)
             watch.step_begin()
+            t0 = time.perf_counter()
             params, opt, metrics = step_fn(params, opt, batch)
-            loss = float(metrics["loss"])
+            loss = float(metrics["loss"])  # waits for the step
+            step_s = time.perf_counter() - t0
             watch.step_end(step)
             history.append(loss)
             if step % loop_cfg.log_every == 0 or step == loop_cfg.steps - 1:
                 log(f"step {step:5d} loss {loss:.4f} gnorm "
-                    f"{float(metrics.get('grad_norm', np.nan)):.3f}")
+                    f"{float(metrics.get('grad_norm', np.nan)):.3f} "
+                    f"time {step_s:.3f}s")
             if ckpt and ((step + 1) % loop_cfg.ckpt_every == 0 or guard.should_stop):
                 ckpt.save(
                     step + 1,

@@ -33,9 +33,11 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     import repro  # noqa: F401  (x64 on, matching every other entry point)
+    from ..launch.compile_cache import enable_compile_cache
     from .cache import calibration_hash, default_cache_path, save_calibration
     from .calibrate import calibrate
 
+    enable_compile_cache()
     cal = calibrate(smoke=args.smoke, blocks=args.blocks,
                     verbose=args.verbose)
     path = save_calibration(cal, args.out or default_cache_path())

@@ -63,6 +63,22 @@ def _measure_mem_bw(smoke: bool) -> float:
     return 2.0 * 4.0 * n / t  # one read + one write of 4-byte elements
 
 
+def _unsupported_errors() -> tuple:
+    import jax
+
+    # a dtype or op this backend does not compile (e.g. c128 on a TPU)
+    return (jax.errors.JaxRuntimeError, NotImplementedError, TypeError)
+
+
+def _unsupported(what: str, err: Exception) -> float:
+    """Record a probe the backend cannot run as rate 0.0, and say so."""
+    import warnings
+
+    warnings.warn(f"calibration: {what} is unsupported here ({err}); "
+                  "recording rate 0.0", stacklevel=3)
+    return 0.0
+
+
 def _measure_int8_ops(smoke: bool) -> float:
     import jax
     import jax.lax as lax
@@ -103,8 +119,8 @@ def _measure_fp8_ops(smoke: bool) -> float:
         )
         t = _time_s(f, a, b)
         return 2.0 * d**3 / t
-    except Exception:
-        return 0.0
+    except _unsupported_errors() as e:
+        return _unsupported("e4m3 dot", e)
 
 
 def _measure_native_rate(dtype_name: str, smoke: bool) -> float:
@@ -123,8 +139,8 @@ def _measure_native_rate(dtype_name: str, smoke: bool) -> float:
         f = jax.jit(jnp.matmul)
         t = _time_s(f, a, a)
         return 8.0 * d**3 / t
-    except Exception:
-        return 0.0
+    except _unsupported_errors() as e:
+        return _unsupported(f"native {dtype_name} matmul", e)
 
 
 def _measure_gemm_launch_s() -> float:

@@ -183,9 +183,10 @@ def _psum_jaxpr(dtype):
 def test_collective_safety_f64_psum_clean():
     jaxpr = _psum_jaxpr(jnp.float64)
     assert CollectiveSafetyPass().run(jaxpr) == []
-    # inside shard_map the collective appears as psum2 in recent jax
+    # inside shard_map the collective appears as psum2 or, with check_vma
+    # on (jax 0.9), psum_invariant
     colls = collect_collectives(jaxpr)
-    assert any(name in ("psum", "psum2") for name, _ in colls)
+    assert any(name in ("psum", "psum2", "psum_invariant") for name, _ in colls)
 
 
 def test_collective_safety_int8_psum_flagged():

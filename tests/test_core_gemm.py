@@ -61,8 +61,8 @@ def test_quantized_product_is_exact(rng):
     a = phi_matrix(rng, (8, 32), 1.0, np.float64)
     b = phi_matrix(rng, (32, 6), 1.0, np.float64)
     e_mu, e_nu = scaling.scale_fast_real(jnp.asarray(a), jnp.asarray(b), ctx)
-    aq = np.asarray(quantize(jnp.asarray(a), scaling.exp2_vector(e_mu), 0))
-    bq = np.asarray(quantize(jnp.asarray(b), scaling.exp2_vector(e_nu), 1))
+    aq = np.asarray(quantize(jnp.asarray(a), scaling.exp2i(e_mu), 0))
+    bq = np.asarray(quantize(jnp.asarray(b), scaling.exp2i(e_nu), 1))
     ai = aq.astype(object).astype(int) if False else np.vectorize(int, otypes=[object])(aq)
     bi = np.vectorize(int, otypes=[object])(bq)
     exact = ai @ bi  # arbitrary-precision integer matmul
@@ -87,8 +87,8 @@ def test_condition4_accurate_mode_extreme_range(rng):
     a = phi_matrix(rng, (M, K), 4.0, np.float64)
     b = phi_matrix(rng, (K, N), 4.0, np.float64)
     e_mu, e_nu = scaling.scale_accurate_real(jnp.asarray(a), jnp.asarray(b), ctx)
-    aq = np.asarray(quantize(jnp.asarray(a), scaling.exp2_vector(e_mu), 0))
-    bq = np.asarray(quantize(jnp.asarray(b), scaling.exp2_vector(e_nu), 1))
+    aq = np.asarray(quantize(jnp.asarray(a), scaling.exp2i(e_mu), 0))
+    bq = np.asarray(quantize(jnp.asarray(b), scaling.exp2i(e_nu), 1))
     ai = np.vectorize(int, otypes=[object])(np.abs(aq))
     bi = np.vectorize(int, otypes=[object])(np.abs(bq))
     bound = ai @ bi

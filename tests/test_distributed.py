@@ -1,9 +1,11 @@
 """Distribution: sharding-rule resolution (unit) + multi-device behaviours
 (subprocess with xla_force_host_platform_device_count=8): compressed
 gradient psum, elastic resharding, sharded train-step parity."""
+import os
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -43,7 +45,7 @@ def test_optimizer_spec_zero1():
     # 2-way data mesh: only .shape is consulted)
     from jax.sharding import AbstractMesh
 
-    amesh = AbstractMesh((("data", 2), ("model", 1)))
+    amesh = AbstractMesh((2, 1), ("data", "model"))
     spec2 = optimizer_spec(P(None, None), (3, 64), amesh)
     assert spec2 == P(None, "data")
 
@@ -71,6 +73,7 @@ _SUBPROCESS_COMMON = textwrap.dedent(
     import jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
     import repro
+    from repro.launch.mesh import make_mesh
     """
 )
 
@@ -82,8 +85,12 @@ def _run_sub(body: str, devices: int = 8):
         capture_output=True,
         text=True,
         timeout=420,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root"},
-        cwd="/root/repo",
+        env={
+            "PYTHONPATH": "src",
+            "PATH": "/usr/bin:/bin",
+            "HOME": os.environ.get("HOME", ""),
+        },
+        cwd=Path(__file__).resolve().parents[1],
     )
     assert res.returncode == 0, f"stdout:\n{res.stdout}\nstderr:\n{res.stderr}"
     return res.stdout
@@ -225,7 +232,7 @@ def test_emulated_train_step_2device_mesh():
         from repro.train.step import make_train_step, init_state
         from repro.optim import AdamWConfig
 
-        mesh = jax.make_mesh((2, 1), ("data", "model"))
+        mesh = make_mesh((2, 1), ("data", "model"))
         cfg = ModelConfig(
             name="tiny", n_layers=2, d_model=32, vocab=64, n_heads=2,
             n_kv_heads=2, head_dim=16, d_ff=64, dtype="float32", remat=True,
@@ -280,7 +287,7 @@ def test_ssd_train_step_2device_mesh_and_index_widths():
         from repro.train.step import make_train_step, init_state
         from repro.optim import AdamWConfig
 
-        mesh = jax.make_mesh((2, 1), ("data", "model"))
+        mesh = make_mesh((2, 1), ("data", "model"))
         cfg = dataclasses.replace(
             get_reduced("mamba2-130m"), dtype="float32", remat=True,
             gemm_policy=GemmPolicy(
@@ -336,7 +343,7 @@ def test_chunked_ce_train_step_2device_mesh():
         from repro.train.step import make_train_step, init_state
         from repro.optim import AdamWConfig
 
-        mesh = jax.make_mesh((1, 2), ("data", "model"))
+        mesh = make_mesh((1, 2), ("data", "model"))
         cfg = ModelConfig(
             name="tiny", n_layers=2, d_model=32, vocab=64, n_heads=2,
             n_kv_heads=2, head_dim=16, d_ff=64, dtype="float32", remat=True,

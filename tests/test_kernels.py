@@ -169,7 +169,7 @@ def test_kernel_pipeline_matches_core_residues(rng):
     e = rng.integers(0, 20, size=m).astype(np.int32)
     s1, s2 = split_scale_exponent(jnp.asarray(e))
     kern = residue_cast(jnp.asarray(a), s1, s2, moduli=ctx.moduli, n_limbs=2)
-    aq = quantize(jnp.asarray(a, jnp.float64), scaling.exp2_vector(jnp.asarray(e)), 0)
+    aq = quantize(jnp.asarray(a, jnp.float64), scaling.exp2i(jnp.asarray(e)), 0)
     core = residues_from_quantized(aq, ctx, 2)
     np.testing.assert_array_equal(np.asarray(kern), np.asarray(core))
 

@@ -1,7 +1,9 @@
 """Pipeline parallelism: pipelined loss/grads == sequential (4 host devices)."""
+import os
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import pytest
 
@@ -52,8 +54,12 @@ def test_pipeline_matches_sequential():
         capture_output=True,
         text=True,
         timeout=420,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root"},
-        cwd="/root/repo",
+        env={
+            "PYTHONPATH": "src",
+            "PATH": "/usr/bin:/bin",
+            "HOME": os.environ.get("HOME", ""),
+        },
+        cwd=Path(__file__).resolve().parents[1],
     )
     assert res.returncode == 0, f"stdout:\n{res.stdout}\nstderr:\n{res.stderr}"
     assert "PIPELINE_OK" in res.stdout

@@ -122,8 +122,8 @@ def test_condition4_fast_mode(phi, seed, n_mod):
     a = (rng.random((8, 48)) - 0.5) * np.exp(rng.standard_normal((8, 48)) * phi)
     b = (rng.random((48, 6)) - 0.5) * np.exp(rng.standard_normal((48, 6)) * phi)
     e_mu, e_nu = scaling.scale_fast_real(jnp.asarray(a), jnp.asarray(b), ctx)
-    aq = np.asarray(quantize(jnp.asarray(a), scaling.exp2_vector(e_mu), 0))
-    bq = np.asarray(quantize(jnp.asarray(b), scaling.exp2_vector(e_nu), 1))
+    aq = np.asarray(quantize(jnp.asarray(a), scaling.exp2i(e_mu), 0))
+    bq = np.asarray(quantize(jnp.asarray(b), scaling.exp2i(e_nu), 1))
     ai = np.vectorize(int, otypes=[object])(np.abs(aq))
     bi = np.vectorize(int, otypes=[object])(np.abs(bq))
     bound = ai @ bi

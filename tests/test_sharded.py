@@ -41,6 +41,7 @@ from repro.distributed.sharding import (
     resolve_gemm_axes,
 )
 from repro.analysis import CollectiveSafetyPass, collect_collectives
+from repro.launch.mesh import make_mesh
 
 M, K, N = FAST_M, FAST_K, FAST_N
 DTYPES = [np.float32, np.float64, np.complex64, np.complex128]
@@ -51,7 +52,7 @@ def _mesh(data=1, model=1, residue=1):
     need = data * model * residue
     if len(jax.devices()) < need:
         pytest.skip(f"needs {need} devices, have {len(jax.devices())}")
-    return jax.make_mesh((data, model, residue), ("data", "model", "residue"))
+    return make_mesh((data, model, residue), ("data", "model", "residue"))
 
 
 def _policy(dtype, execution, **kw):
@@ -325,8 +326,8 @@ def test_matmul_jit_resolves_ambient_mesh_before_cache(rng):
         pytest.skip("needs 4 devices")
     x, w = _operands(rng, np.float32)
     pol = _policy(np.float32, "sharded")
-    mesh1 = jax.make_mesh((1, 1, 2), ("data", "model", "residue"))
-    mesh2 = jax.make_mesh((1, 1, 4), ("data", "model", "residue"))
+    mesh1 = make_mesh((1, 1, 2), ("data", "model", "residue"))
+    mesh2 = make_mesh((1, 1, 4), ("data", "model", "residue"))
     with repro.use_mesh(mesh1):
         y1 = linalg.matmul_jit(x, w, policy=pol)
     with repro.use_mesh(mesh2):
@@ -340,7 +341,7 @@ def test_resolve_gemm_axes_rules():
     axes = resolve_gemm_axes(mesh)
     assert axes == GemmShardAxes(residue="residue", m="data", n="model")
     # no residue axis: fall back to model, which then can't also carry n
-    mesh2 = jax.make_mesh((1, 1), ("data", "model"))
+    mesh2 = make_mesh((1, 1), ("data", "model"))
     assert resolve_gemm_axes(mesh2) == GemmShardAxes(
         residue="model", m="data", n=None
     )
