@@ -6,9 +6,9 @@
 One chip: sgemm, dgemm, cgemm and zgemm at m = k = n = 8192 (fast mode,
 default moduli counts) on the `kernel` and `fused` executions, then three
 training steps of mamba2-130m at its published widths through
-`repro.launch.train`.  Four chips: zgemm at 8192 under
-`execution="sharded"` on a (1, 1, 4) residue mesh and a (2, 2) m/n mesh,
-each bitwise against the one-device `kernel` result, and nothing else.
+`repro.launch.train`.  Four chips: zgemm under `execution="sharded"` on a
+(1, 1, 4) residue mesh at 4096 and a (2, 2) m/n mesh at 8192, each bitwise
+against the one-device `kernel` result, and nothing else.
 
 Everything runs in this one process.  Without a TPU the script exits
 nonzero before any phase; any failed check raises.  The last line of
@@ -260,65 +260,67 @@ def model_phase(*, reduced: bool = False, steps: int = 3, batch: int = 4,
 
 
 def four_chip_phase(n: int = SIZE, *, seed: int = 0, interpret: bool = False):
-    """Sharded zgemm on a residue mesh and an m/n mesh vs one device."""
+    """Sharded zgemm on a residue mesh and an m/n mesh vs one device.
+
+    The residue mesh runs at n/2: at n = 8192 its exact f64 partial planes
+    need 51.5 GB a device (compiled for a described v5e:2x2).
+    """
     import jax
     from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
     from repro.core.policy import GemmPolicy
     from repro.launch.mesh import make_mesh
 
-    # name: (mesh shape, mesh axes, (residue, m, n) GEMM axes, output shard)
+    # name: (mesh shape, mesh axes, (residue, m, n) GEMM axes, size, shard)
     layouts = {
         "residue (1,1,4)": ((1, 1, 4), ("data", "model", "residue"),
-                            ("residue", None, None), (n, n)),
+                            ("residue", None, None), n // 2, (n // 2, n // 2)),
         "m/n (2,2)": ((2, 2), ("data", "model"),
-                      (None, "data", "model"), (n // 2, n // 2)),
+                      (None, "data", "model"), n, (n // 2, n // 2)),
     }
-    placement = {"one device": SingleDeviceSharding(jax.devices()[0])}
-    jobs = {
-        "one device": (
-            routine("zgemm", GemmPolicy(execution="kernel", interpret=interpret)),
-            arg_shapes("zgemm", n, placement["one device"]),
-        )
-    }
-    for name, (shape, axes, gemm_axes, _) in layouts.items():
+    one = SingleDeviceSharding(jax.devices()[0])
+    kernel = routine("zgemm", GemmPolicy(execution="kernel", interpret=interpret))
+    placement, jobs = {}, {}
+    for name, (shape, axes, gemm_axes, size, _) in layouts.items():
+        jobs[("one device", size)] = (kernel, arg_shapes("zgemm", size, one))
         mesh = make_mesh(shape, axes)
         placement[name] = NamedSharding(mesh, PartitionSpec())  # replicated
         policy = GemmPolicy(
             execution="sharded", mesh=mesh, shard_axes=gemm_axes,
             interpret=interpret,
         )
-        jobs[name] = (routine("zgemm", policy), arg_shapes("zgemm", n, placement[name]))
+        jobs[name] = (routine("zgemm", policy), arg_shapes("zgemm", size, placement[name]))
     programs = compile_all(jobs, interpret=interpret)
 
-    rng = np.random.default_rng(seed)
-    a, b = operands(rng, np.complex128, n)
-    ref = None
-    for name, (compiled, comp_s) in programs.items():
+    for name, (_, _, _, size, shard_want) in layouts.items():
+        a, b = operands(np.random.default_rng(seed), np.complex128, size)
+        ref_prog, ref_comp_s = programs[("one device", size)]
+        out, _, run_s = run_twice(
+            ref_prog, device_args("zgemm", a, b, lambda x: jax.device_put(x, one))
+        )
+        ref = to_host("zgemm", out)
+        log(f"sharded zgemm n={size} one device kernel: compile "
+            f"{ref_comp_s:.2f}s run {run_s:.4f}s")
+        compiled, comp_s = programs[name]
         args = device_args(
             "zgemm", a, b, lambda x: jax.device_put(x, placement[name])
         )
         out, _, run_s = run_twice(compiled, args)
-        c = to_host("zgemm", out)
-        if ref is None:
-            ref = c
-            log(f"sharded zgemm {name} kernel: compile {comp_s:.2f}s "
-                f"run {run_s:.4f}s")
-            continue
         text = compiled.as_text()
         parts = re.search(r"num_partitions=(\d+)", text)
         parts = int(parts.group(1)) if parts else 1
         devices = sorted({d.id for p in out for d in p.sharding.device_set})
         shard = out[0].sharding.shard_shape(out[0].shape)
-        same = bitwise_equal(c, ref)
+        same = bitwise_equal(to_host("zgemm", out), ref)
         log(
-            f"sharded zgemm {name}: compile {comp_s:.2f}s run {run_s:.4f}s "
-            f"partitions {parts} output devices {devices} shard {shard} "
-            f"all-reduce {'all-reduce' in text} == one-device bitwise: {same}"
+            f"sharded zgemm n={size} {name}: compile {comp_s:.2f}s run "
+            f"{run_s:.4f}s partitions {parts} output devices {devices} shard "
+            f"{shard} all-reduce {'all-reduce' in text} == one-device "
+            f"bitwise: {same}"
         )
         if not same:
             raise AssertionError(f"{name}: sharded zgemm differs from one device")
-        if len(devices) != 4 or parts != 4 or shard != layouts[name][3]:
+        if len(devices) != 4 or parts != 4 or shard != shard_want:
             raise AssertionError(f"{name}: work not spread over 4 devices")
         del out, args
 
