@@ -6,9 +6,9 @@
 One chip: sgemm, dgemm, cgemm and zgemm at m = k = n = 8192 (fast mode,
 default moduli counts) on the `kernel` and `fused` executions, then three
 training steps of mamba2-130m at its published widths through
-`repro.launch.train`.  Four chips: zgemm under `execution="sharded"` on a
-(1, 1, 4) residue mesh at 4096 and a (2, 2) m/n mesh at 8192, each bitwise
-against the one-device `kernel` result, and nothing else.
+`repro.launch.train`.  Four chips: zgemm at 8192 under
+`execution="sharded"` on a (1, 1, 4) residue mesh and a (2, 2) m/n mesh,
+each bitwise against the one-device `kernel` result, and nothing else.
 
 Everything runs in this one process.  Without a TPU the script exits
 nonzero before any phase; any failed check raises.  The last line of
@@ -96,25 +96,50 @@ def to_host(family: str, out) -> np.ndarray:
     return np.asarray(out)
 
 
-def compile_all(jobs: dict, *, interpret: bool) -> dict:
-    """{key: (compiled, compile s)} of {key: (fn, arg shapes)}, compiled
-    concurrently (the compiler releases the interpreter lock), each checked
-    to hold a Pallas TPU kernel."""
+class CacheEvents:
+    """Counts of JAX's persistent compilation cache hits and misses."""
+
+    def __init__(self):
+        import jax.monitoring
+
+        self.hits = self.misses = 0
+        jax.monitoring.register_event_listener(self._event)
+
+    def _event(self, name, **_):
+        if name == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif name == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+    def since(self, mark):
+        """'h hits, m misses' since `mark`, a (hits, misses) pair."""
+        return f"{self.hits - mark[0]} hits, {self.misses - mark[1]} misses"
+
+
+def compile_all(jobs: dict, cache: CacheEvents) -> dict:
+    """{key: (compiled, compile s)} of {key: (fn, arg shapes)}, each checked
+    to hold a Pallas TPU kernel.  Lowering holds the interpreter lock, so
+    the programs are lowered one by one, then compiled (or read from the
+    persistent cache) concurrently."""
     import jax
 
-    def one(item):
-        fn, shapes = item
-        t0 = time.perf_counter()
-        compiled = jax.jit(fn).lower(*shapes).compile()
-        return compiled, time.perf_counter() - t0
-
     t0 = time.perf_counter()
+    lowered = {key: jax.jit(fn).lower(*shapes) for key, (fn, shapes) in jobs.items()}
+    t1 = time.perf_counter()
+    mark = (cache.hits, cache.misses)
+
+    def one(low):
+        t = time.perf_counter()
+        compiled = low.compile()
+        return compiled, time.perf_counter() - t
+
     with ThreadPoolExecutor(max_workers=len(jobs)) as pool:
-        done = dict(zip(jobs, pool.map(one, jobs.values())))
-    log(f"compiled {len(jobs)} programs concurrently in "
-        f"{time.perf_counter() - t0:.1f}s")
+        done = dict(zip(lowered, pool.map(one, lowered.values())))
+    log(f"lowered {len(jobs)} programs in {t1 - t0:.1f}s, compiled them "
+        f"concurrently in {time.perf_counter() - t1:.1f}s; compile cache "
+        f"{cache.since(mark)}")
     for key, (compiled, _) in done.items():
-        if not interpret and "tpu_custom_call" not in compiled.as_text():
+        if "tpu_custom_call" not in compiled.as_text():
             raise AssertionError(f"{key}: compiled program holds no TPU kernel")
     return done
 
@@ -159,8 +184,8 @@ def arg_shapes(family: str, n: int, sharding):
     return (spec,) * (4 if family == "zgemm" else 2)
 
 
-def gemm_phase(n: int = SIZE, *, seed: int = 0, interpret: bool = False):
-    """Every family on `kernel` and `fused` at n^3, checked on the host."""
+def gemm_phase(seed: int, cache: CacheEvents):
+    """Every family on `kernel` and `fused` at SIZE^3, checked on the host."""
     import jax
     from jax.sharding import SingleDeviceSharding
 
@@ -169,23 +194,22 @@ def gemm_phase(n: int = SIZE, *, seed: int = 0, interpret: bool = False):
 
     one = SingleDeviceSharding(jax.devices()[0])
     policies = {
-        ex: GemmPolicy(mode="fast", execution=ex, interpret=interpret)
-        for ex in ("kernel", "fused")
+        ex: GemmPolicy(mode="fast", execution=ex) for ex in ("kernel", "fused")
     }
     programs = compile_all(
         {
-            (family, ex): (routine(family, pol), arg_shapes(family, n, one))
+            (family, ex): (routine(family, pol), arg_shapes(family, SIZE, one))
             for family in FAMILIES
             for ex, pol in policies.items()
         },
-        interpret=interpret,
+        cache,
     )
     rng = np.random.default_rng(seed)
     for family, dtype in FAMILIES.items():
-        a, b = operands(rng, dtype, n)
+        a, b = operands(rng, dtype, SIZE)
         args = device_args(family, a, b, jax.device_put)
         n_moduli = default_n_moduli(dtype, "fast")
-        rows = np.sort(rng.choice(n, size=min(SAMPLE_ROWS, n), replace=False))
+        rows = np.sort(rng.choice(SIZE, size=SAMPLE_ROWS, replace=False))
         outs = {}
         for execution, policy in policies.items():
             compiled, comp_s = programs.pop((family, execution))
@@ -197,7 +221,7 @@ def gemm_phase(n: int = SIZE, *, seed: int = 0, interpret: bool = False):
                 family, c, a, b, rows, n_moduli, policy.formulation
             )
             log(
-                f"gemm {family} {execution} n={n} N={n_moduli}: compile "
+                f"gemm {family} {execution} n={SIZE} N={n_moduli}: compile "
                 f"{comp_s:.2f}s first-run {first_s:.4f}s run {run_s:.4f}s "
                 f"{metric} {err:.3e} <= {lim:.3e}"
             )
@@ -213,16 +237,13 @@ def gemm_phase(n: int = SIZE, *, seed: int = 0, interpret: bool = False):
             from repro import linalg
 
             small = 64
-            z = linalg.zgemm(
-                a[:small, :small], b[:small, :small],
-                policy=GemmPolicy(execution="kernel", interpret=interpret),
-            )
+            kernel = GemmPolicy(execution="kernel")
+            z = linalg.zgemm(a[:small, :small], b[:small, :small], policy=kernel)
             zp = to_host(
                 "zgemm",
-                jax.jit(routine("zgemm", GemmPolicy(
-                    execution="kernel", interpret=interpret
-                )))(*device_args("zgemm", a[:small, :small], b[:small, :small],
-                                 jax.device_put)),
+                jax.jit(routine("zgemm", kernel))(*device_args(
+                    "zgemm", a[:small, :small], b[:small, :small], jax.device_put
+                )),
             )
             ok = z.dtype == np.complex128 and bitwise_equal(z, zp)
             log(f"gemm zgemm c128 entry point == planar program: {ok}")
@@ -231,14 +252,13 @@ def gemm_phase(n: int = SIZE, *, seed: int = 0, interpret: bool = False):
         del args, outs
 
 
-def model_phase(*, reduced: bool = False, steps: int = 3, batch: int = 4,
-                seq: int = 1024):
+def model_phase(cache: CacheEvents, steps: int = 3):
     """mamba2-130m through `repro.launch.train`: emulated vs native.  The
     launcher draws its weights and batches from its own fixed seed."""
     from repro.launch import train
 
-    base = ["--arch", "mamba2-130m", "--batch", str(batch), "--seq", str(seq)]
-    base += [] if reduced else ["--full"]
+    base = ["--arch", "mamba2-130m", "--full", "--batch", "4", "--seq", "1024"]
+    mark = (cache.hits, cache.misses)
     log("model native (1 step):")
     native = train.main(base + ["--steps", "1", "--backend", "native"])
     log(f"model ozaki2_f32 kernel ({steps} steps):")
@@ -257,51 +277,47 @@ def model_phase(*, reduced: bool = False, steps: int = 3, batch: int = 4,
         raise AssertionError(f"loss did not decrease: {emu}")
     if not rel <= LOSS_RTOL:
         raise AssertionError(f"step-1 loss off native by {rel:.3e}")
+    log(f"model phase compile cache {cache.since(mark)}")
 
 
-def four_chip_phase(n: int = SIZE, *, seed: int = 0, interpret: bool = False):
-    """Sharded zgemm on a residue mesh and an m/n mesh vs one device.
-
-    The residue mesh runs at n/2: at n = 8192 its exact f64 partial planes
-    need 51.5 GB a device (compiled for a described v5e:2x2).
-    """
+def four_chip_phase(seed: int, cache: CacheEvents):
+    """Sharded zgemm on a residue mesh and an m/n mesh vs one device."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
     from repro.core.policy import GemmPolicy
     from repro.launch.mesh import make_mesh
 
-    # name: (mesh shape, mesh axes, (residue, m, n) GEMM axes, size, shard)
+    half = SIZE // 2
+    # name: (mesh shape, mesh axes, (residue, m, n) GEMM axes, output shard)
     layouts = {
         "residue (1,1,4)": ((1, 1, 4), ("data", "model", "residue"),
-                            ("residue", None, None), n // 2, (n // 2, n // 2)),
+                            ("residue", None, None), (SIZE, SIZE)),
         "m/n (2,2)": ((2, 2), ("data", "model"),
-                      (None, "data", "model"), n, (n // 2, n // 2)),
+                      (None, "data", "model"), (half, half)),
     }
     one = SingleDeviceSharding(jax.devices()[0])
-    kernel = routine("zgemm", GemmPolicy(execution="kernel", interpret=interpret))
-    placement, jobs = {}, {}
-    for name, (shape, axes, gemm_axes, size, _) in layouts.items():
-        jobs[("one device", size)] = (kernel, arg_shapes("zgemm", size, one))
+    placement = {}
+    jobs = {"one device": (routine("zgemm", GemmPolicy(execution="kernel")),
+                           arg_shapes("zgemm", SIZE, one))}
+    for name, (shape, axes, gemm_axes, _) in layouts.items():
         mesh = make_mesh(shape, axes)
         placement[name] = NamedSharding(mesh, PartitionSpec())  # replicated
-        policy = GemmPolicy(
-            execution="sharded", mesh=mesh, shard_axes=gemm_axes,
-            interpret=interpret,
-        )
-        jobs[name] = (routine("zgemm", policy), arg_shapes("zgemm", size, placement[name]))
-    programs = compile_all(jobs, interpret=interpret)
+        policy = GemmPolicy(execution="sharded", mesh=mesh, shard_axes=gemm_axes)
+        jobs[name] = (routine("zgemm", policy), arg_shapes("zgemm", SIZE, placement[name]))
+    programs = compile_all(jobs, cache)
 
-    for name, (_, _, _, size, shard_want) in layouts.items():
-        a, b = operands(np.random.default_rng(seed), np.complex128, size)
-        ref_prog, ref_comp_s = programs[("one device", size)]
-        out, _, run_s = run_twice(
-            ref_prog, device_args("zgemm", a, b, lambda x: jax.device_put(x, one))
-        )
-        ref = to_host("zgemm", out)
-        log(f"sharded zgemm n={size} one device kernel: compile "
-            f"{ref_comp_s:.2f}s run {run_s:.4f}s")
-        compiled, comp_s = programs[name]
+    a, b = operands(np.random.default_rng(seed), np.complex128, SIZE)
+    compiled, comp_s = programs.pop("one device")
+    out, _, run_s = run_twice(
+        compiled, device_args("zgemm", a, b, lambda x: jax.device_put(x, one))
+    )
+    ref = to_host("zgemm", out)
+    del out
+    log(f"sharded zgemm n={SIZE} one device kernel: compile {comp_s:.2f}s "
+        f"run {run_s:.4f}s")
+    for name, (_, _, _, shard_want) in layouts.items():
+        compiled, comp_s = programs.pop(name)
         args = device_args(
             "zgemm", a, b, lambda x: jax.device_put(x, placement[name])
         )
@@ -313,7 +329,7 @@ def four_chip_phase(n: int = SIZE, *, seed: int = 0, interpret: bool = False):
         shard = out[0].sharding.shard_shape(out[0].shape)
         same = bitwise_equal(to_host("zgemm", out), ref)
         log(
-            f"sharded zgemm n={size} {name}: compile {comp_s:.2f}s run "
+            f"sharded zgemm n={SIZE} {name}: compile {comp_s:.2f}s run "
             f"{run_s:.4f}s partitions {parts} output devices {devices} shard "
             f"{shard} all-reduce {'all-reduce' in text} == one-device "
             f"bitwise: {same}"
@@ -322,7 +338,7 @@ def four_chip_phase(n: int = SIZE, *, seed: int = 0, interpret: bool = False):
             raise AssertionError(f"{name}: sharded zgemm differs from one device")
         if len(devices) != 4 or parts != 4 or shard != shard_want:
             raise AssertionError(f"{name}: work not spread over 4 devices")
-        del out, args
+        del out, args, compiled
 
 
 def main(argv=None) -> int:
@@ -340,16 +356,16 @@ def main(argv=None) -> int:
         sys.exit(f"chip_smoke: the repro package is not beside this script: {e}")
     import jax
 
-    cache = enable_compile_cache()
     log(f"device {dev.platform} {dev.device_kind} x{len(jax.devices())}; "
-        f"compile cache {cache}")
+        f"compile cache {enable_compile_cache()}")
+    cache = CacheEvents()
     t0 = time.perf_counter()
     if args.four_chips:
-        four_chip_phase(seed=args.seed)
+        four_chip_phase(args.seed, cache)
     else:
-        gemm_phase(seed=args.seed)
+        gemm_phase(args.seed, cache)
         log(f"gemm phase {time.perf_counter() - t0:.1f}s")
-        model_phase()
+        model_phase(cache)
     log(f"total {time.perf_counter() - t0:.1f}s")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

@@ -5,7 +5,7 @@ A pass framework over traced jaxprs plus source-level lints, wired into CI
 invariants every engine must uphold (see docs/static_analysis.md):
 
 * :class:`OverflowPass` — int8 residue dots within ``K_CHUNK_LIMIT``, fp8
-  digit dots within ``FP8_K_CHUNK_LIMIT``, CRT partial f64 dots within the
+  digit dots within ``FP8_K_CHUNK_LIMIT``, provable f64 dots within the
   exact 2^53 window (paper SIII-A accumulation bound);
 * :class:`CollectiveSafetyPass` — only >=32-bit (exact) arrays cross the
   mesh in collectives;

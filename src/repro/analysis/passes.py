@@ -23,15 +23,15 @@ names the violated invariant and where it was found.  The passes:
       cross-term dots, which concatenate two digit planes along K.
     * every f64 `dot_general` whose operand magnitudes are *provable*
       (from consts, or int8/fp8 inputs converted to f64) must satisfy
-      |lhs| * |rhs| * eff_k <= 2^53 — the exact-f64-integer window the CRT
-      partial-split reconstruction relies on.  Unprovable f64/f32/bf16
+      |lhs| * |rhs| * eff_k <= 2^53 — the exact-f64-integer window.
+      Unprovable f64/f32/bf16
       dots are out of scope (ordinary float compute) and never flagged.
 
 ``CollectiveSafetyPass``
     No low-precision array may cross the mesh: any collective
     (psum/pmax/pmin/all_gather/all_to_all/ppermute/reduce_scatter/...)
     with an operand dtype narrower than 4 bytes is a finding.  The sharded
-    pipeline's contract is that only exact f64 CRT partials (and int32
+    pipeline's contract is that only exact int32 CRT partials (and int32
     exponent scalars) are communicated.
 
 ``LaunchCountPass``
@@ -334,7 +334,7 @@ class OverflowPass:
                                 self.name,
                                 f"f64 dot_general partial sum bounded by "
                                 f"{lb:g} * {rb:g} * K={eff} = {worst:.3g} > "
-                                "2^53: CRT partial-combine would round",
+                                "2^53: the f64 sum would round",
                                 primitive="dot_general",
                                 path=path,
                             )
@@ -380,7 +380,7 @@ class CollectiveSafetyPass(JaxprPass):
                     Finding(
                         self.name,
                         f"{dt} array crosses the mesh via "
-                        f"`{eqn.primitive.name}`; only exact f64 CRT "
+                        f"`{eqn.primitive.name}`; only exact int32 CRT "
                         "partials (and >=32-bit scalars) may be "
                         "communicated",
                         primitive=eqn.primitive.name,
@@ -392,7 +392,7 @@ class CollectiveSafetyPass(JaxprPass):
 def collect_collectives(jaxpr) -> list:
     """All collective eqns in `jaxpr` as (primitive_name, [operand dtypes]).
 
-    Positive-evidence helper for tests: e.g. assert an f64 psum exists in a
+    Positive-evidence helper for tests: e.g. assert an int32 psum exists in a
     sharded trace (the CollectiveSafetyPass alone would also pass on a
     program with no communication at all).
     """
@@ -529,9 +529,9 @@ def certify_partial_split(moduli, u=None, part_bits=None) -> list:
 
     Checks (see core/crt.partial_split): every entry of the combine table
     ``u`` is a nonnegative integer below ``2**part_bits``, and the worst
-    partial sum ``max(u) * 127 * N`` stays within the exact f64 integer
-    window (2^53) — so `partial_combine`'s f64 tensordot is exact for any
-    residue inputs.  Pass `u` / `part_bits` explicitly to audit a foreign
+    partial sum ``max(u) * 127 * N`` stays within int32 (below 2^31) — so
+    `partial_combine`'s int32 multiply-adds are exact for any residue
+    inputs.  Pass `u` / `part_bits` explicitly to audit a foreign
     table; by default the tables are recomputed from `moduli`.
     """
     from ..core import crt
@@ -548,7 +548,7 @@ def certify_partial_split(moduli, u=None, part_bits=None) -> list:
     if np.any(u < 0) or np.any(u != np.floor(u)):
         findings.append(
             Finding(name, "partial-split table u has non-integer or negative "
-                          "entries; f64 reconstruction is not exact")
+                          "entries; the reconstruction is not exact")
         )
     if u.size and float(np.max(u)) >= 2.0 ** int(part_bits):
         findings.append(
@@ -559,12 +559,12 @@ def certify_partial_split(moduli, u=None, part_bits=None) -> list:
             )
         )
     worst = (float(np.max(u)) if u.size else 0.0) * 127.0 * n
-    if worst > 2.0**53:
+    if worst >= 2.0**31:
         findings.append(
             Finding(
                 name,
-                f"worst CRT partial sum max(u)*127*N = {worst:.3g} > 2^53; "
-                "partial_combine's f64 accumulation would round",
+                f"worst CRT partial sum max(u)*127*N = {worst:.3g} >= 2^31; "
+                "partial_combine's int32 accumulation would overflow",
             )
         )
     return findings

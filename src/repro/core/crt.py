@@ -26,7 +26,7 @@ import numpy as np
 
 from .expansion import dd_add, dd_mul_fp, two_prod, quick_two_sum
 from .moduli import CRTContext
-from .residues import num_limbs_for_bits, residues_from_quantized, sym_mod_int32, sym_mod_small
+from .residues import sym_mod_int32, sym_mod_small
 from .scaling import exp2i
 
 _F64 = jnp.float64
@@ -138,9 +138,15 @@ def reconstruct(e_res: jnp.ndarray, ctx: CRTContext, method: str = "paper"):
 
 
 def inverse_scale(hi, lo, e_mu, e_nu, out_dtype):
-    """C = diag(mu)^-1 C' diag(nu)^-1 — exact (powers of two)."""
-    inv = exp2i(-(e_mu[:, None] + e_nu[None, :]))
-    return ((hi * inv) + (lo * inv)).astype(out_dtype)
+    """C = diag(mu)^-1 C' diag(nu)^-1 — exact (powers of two).
+
+    The power 2^-(e_mu + e_nu) is applied as two halves: with small
+    operands on both sides the whole power leaves the exponent range while
+    each half, and C itself, stays within it."""
+    e = -(e_mu[:, None] + e_nu[None, :])
+    e1 = e // 2
+    inv1, inv2 = exp2i(e1), exp2i(e - e1)
+    return ((hi * inv1) * inv2 + (lo * inv1) * inv2).astype(out_dtype)
 
 
 # ==================================== partial (sharded) reconstruction support
@@ -152,17 +158,18 @@ def inverse_scale(hi, lo, e_mu, e_nu, out_dtype):
 #
 #     S = sum_l w_l E_l,      w_l = (P/p_l) q_l  (exact Python integers)
 #
-# in an *unevaluated multi-part f64 split*: w_l is cut at fixed absolute bit
-# positions into parts of at most 53 - 7 - ceil(log2 N) bits, so every
-# product u_{j,l} * E_l and every partial/total sum of them is an exact f64
-# integer — addition of exact integers below 2^53 is associative, hence a
-# `psum` over devices is bitwise order-independent.  Since w_l === delta_{li}
-# (mod p_i), the full S satisfies S === E_i (mod p_i), so after the psum each
-# device re-derives the COMPLETE residue planes from the exact parts in local
+# in an *unevaluated multi-part int32 split*: w_l is cut at fixed absolute
+# bit positions into parts of at most 31 - 7 - ceil(log2 N) bits, so every
+# product u_{j,l} * E_l and every partial/total sum of them is an exact int32
+# — integer addition is associative, hence a `psum` over devices is bitwise
+# order-independent on any hardware (a TPU's emulated f64 is not exact here,
+# so the split does not use it).  Since w_l === delta_{li} (mod p_i), the
+# full S satisfies S === E_i (mod p_i), so after the psum each device
+# re-derives the COMPLETE residue planes from the exact parts in local
 # small-integer arithmetic (`residues_from_partial`) and hands them to the
-# ordinary (kernel or reference) reconstructor — whose output is therefore
-# bitwise identical to the single-device run on the same planes, for every
-# sharding of the residue dimension.
+# ordinary reconstructor — whose output is therefore bitwise identical to
+# the single-device run on the same planes, for every sharding of the
+# residue dimension.
 
 
 @functools.lru_cache(maxsize=None)
@@ -171,15 +178,14 @@ def partial_split(moduli: tuple[int, ...]):
 
     Returns ``(u, radix, part_bits)``:
 
-    * ``u``: (n_parts, N) f64 — ``u[j, l]`` is bits [j*part_bits, (j+1)*
-      part_bits) of w_l as an exact small float, so
-      ``w_l == sum_j u[j, l] * 2**(j*part_bits)`` exactly;
+    * ``u``: (n_parts, N) int32 — ``u[j, l]`` is bits [j*part_bits, (j+1)*
+      part_bits) of w_l, so ``w_l == sum_j u[j, l] * 2**(j*part_bits)``;
     * ``radix``: (n_parts, N) int32 — symmetric residues of
       ``2**(j*part_bits) mod p_l`` (the rebuild table);
-    * ``part_bits``: the per-part width, 53 - 7 - ceil(log2 N), sized so
-      ``sum_l u[j, l] * E_l`` over all N planes stays below 2^53 (|E| <= 127
+    * ``part_bits``: the per-part width, 31 - 7 - ceil(log2 N), sized so
+      ``sum_l u[j, l] * E_l`` over all N planes stays below 2^31 (|E| <= 127
       needs 7 bits, the N-term sum ceil(log2 N) more) — i.e. every partial
-      sum any device or collective can form is an exact f64 integer.
+      sum any device or collective can form is an exact int32.
     """
     n = len(moduli)
     P = 1
@@ -189,57 +195,52 @@ def partial_split(moduli: tuple[int, ...]):
     for p in moduli:
         M = P // p
         ws.append(M * pow(M % p, -1, p))
-    part_bits = 53 - 7 - max(1, math.ceil(math.log2(max(n, 2))))
+    part_bits = 31 - 7 - max(1, math.ceil(math.log2(max(n, 2))))
     n_parts = max(1, -(-max(w.bit_length() for w in ws) // part_bits))
-    u = np.zeros((n_parts, n), dtype=np.float64)
+    u = np.zeros((n_parts, n), dtype=np.int32)
     radix = np.zeros((n_parts, n), dtype=np.int32)
     mask = (1 << part_bits) - 1
     for l, (w, p) in enumerate(zip(ws, moduli)):
         half = (p - 1) // 2
         for j in range(n_parts):
-            u[j, l] = float((w >> (j * part_bits)) & mask)
+            u[j, l] = (w >> (j * part_bits)) & mask
             r = pow(2, j * part_bits, p)
             radix[j, l] = r - p if r > half else r
     return u, radix, part_bits
 
 
 def partial_combine(e_res: jnp.ndarray, u: jnp.ndarray) -> jnp.ndarray:
-    """(..., N_local, m, n) int8 planes -> (..., n_parts, m, n) f64 partials.
+    """(..., N_local, m, n) int8 planes -> (..., n_parts, m, n) int32 partials.
 
     ``u`` is this shard's (n_parts, N_local) column slice of the
     `partial_split` table (zero columns for padding planes).  Every product
-    and sum is an exact f64 integer by the part_bits budget, so the result
-    can be `psum`-reduced over the residue mesh axis bitwise
-    order-independently.
+    and sum is an exact int32 by the part_bits budget, so the result can be
+    `psum`-reduced over the residue mesh axis bitwise order-independently.
     """
-    ef = e_res.astype(_F64)
-    # contract the plane axis (third from last) against u's columns
-    return jnp.moveaxis(
-        jnp.tensordot(u, jnp.moveaxis(ef, -3, 0), axes=[[1], [0]]), 0, -3
-    )
+    ei = e_res.astype(jnp.int32)
+    t = None
+    for l in range(u.shape[1]):  # N_local multiply-adds; no integer dot
+        term = u[:, l].reshape(-1, 1, 1) * ei[..., l : l + 1, :, :]
+        t = term if t is None else t + term
+    return t
 
 
 def residues_from_partial(t_parts: jnp.ndarray, ctx: CRTContext) -> jnp.ndarray:
-    """Exact f64 partial sums (n_parts, ...) -> full (N, ...) int8 residues.
+    """Exact int32 partial sums (n_parts, ...) -> full (N, ...) int8 residues.
 
     ``t_parts[j] == sum_l u[j, l] * E_l`` summed over ALL planes (i.e. after
-    the psum).  Rebuilds E_i = sym_mod(sum_j t_j 2^(j*part_bits), p_i) in
-    small exact integer arithmetic: each t_j (< 2^53) limb-splits through the
-    standard residue decomposition, then combines with the 2^(j*part_bits)
-    radix residues.  The output equals the residues a single device holding
-    every plane would have computed — bit for bit.
+    the psum).  Rebuilds E_i = sym_mod(sum_j t_j 2^(j*part_bits), p_i) from
+    each t_j's residue and the 2^(j*part_bits) radix residue, in small exact
+    int32 arithmetic.  The output equals the residues a single device
+    holding every plane would have computed — bit for bit.
     """
-    u, radix, _ = partial_split(ctx.moduli)
-    n_parts = u.shape[0]
-    nl = num_limbs_for_bits(53.0)
-    acc = None
-    for j in range(n_parts):
-        planes = residues_from_quantized(t_parts[j], ctx, nl).astype(jnp.int32)
-        r = jnp.asarray(radix[j], jnp.int32).reshape(
-            (ctx.n,) + (1,) * (t_parts.ndim - 1)
+    _, radix, _ = partial_split(ctx.moduli)
+    outs = []
+    for l, p in enumerate(ctx.moduli):
+        # |each term| <= 127^2, n_parts of them: far inside int32
+        acc = sum(
+            sym_mod_int32(t_parts[j], int(p)) * int(radix[j, l])
+            for j in range(radix.shape[0])
         )
-        term = planes * r  # |term| <= 127^2
-        acc = term if acc is None else acc + term
-    # |acc| <= n_parts * 127^2 << 2^31: exact final symmetric reduction
-    outs = [sym_mod_int32(acc[l], int(p)) for l, p in enumerate(ctx.moduli)]
+        outs.append(sym_mod_int32(acc, int(p)))
     return jnp.stack(outs, axis=0).astype(jnp.int8)

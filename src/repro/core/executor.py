@@ -199,7 +199,15 @@ class ReferenceBackend:
         return _composed_karatsuba(self, arr, ari, brr, bri, ctx)
 
     def reconstruct(self, e_res, e_mu, e_nu, ctx, method, out_dtype):
-        """CRT reconstruction (steps V-v/vi) + exact inverse scaling."""
+        """CRT reconstruction (steps V-v/vi) + exact inverse scaling.
+
+        Exact only in IEEE f64.  A TPU emulates f64 with less precision and
+        f32's exponent range, so there this path refuses to trace."""
+        if jax.default_backend() == "tpu":
+            raise NotImplementedError(
+                "execution='reference' needs IEEE f64, which the TPU does not "
+                "have; use execution='kernel' or 'fused' on a TPU"
+            )
         hi, lo = crt.reconstruct(e_res, ctx, method)
         return crt.inverse_scale(hi, lo, e_mu, e_nu, out_dtype)
 

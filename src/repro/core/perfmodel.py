@@ -19,7 +19,7 @@ Beyond-paper terms live here too, because the 'auto' plan selections
   `select_engine` compares the two per shape;
 
 * a *communication term* for `GemmPolicy(execution="sharded")` — the exact
-  partial-reconstruction combine psums `crt_partial_parts(N)` f64 planes of
+  partial-reconstruction combine psums `crt_partial_parts(N)` int32 planes of
   the output over the residue mesh axis (`sharded_comm_time_s`), so plans
   for sharded GEMMs are selected on per-shard shapes plus that cost;
 * the *kernel block-selection* rule (`select_block`) shared with
@@ -236,7 +236,7 @@ def real_tflops(m, n, k, n_moduli, hw: HW, mode="fast", prec="d", c=None,
 
 
 def crt_partial_parts(n_moduli: int) -> int:
-    """Number of exact f64 part-planes the sharded combine psums per output
+    """Number of exact int32 part-planes the sharded combine psums per output
     element (the `core/crt.partial_split` width for the default moduli)."""
     from .crt import partial_split
     from .moduli import default_moduli
@@ -256,7 +256,7 @@ def sharded_comm_time_s(
     """Communication term of one sharded emulated GEMM (per-shard m, n).
 
     The residue-sharded pipeline communicates exactly one thing: the psum of
-    the `crt_partial_parts(N)` exact f64 partial-reconstruction planes over
+    the `crt_partial_parts(N)` exact int32 partial-reconstruction planes over
     the residue axis (complex outputs stack CR/CI, 2x).  No int8 residue
     plane ever crosses the mesh — that invariant is CI-asserted against the
     traced jaxpr.  Ring all-reduce moves ~(r-1)/r of the payload per device.
@@ -266,7 +266,7 @@ def sharded_comm_time_s(
     hw = hw or default_hw()
     parts = crt_partial_parts(n_moduli)
     stack = 2 if complex_ else 1
-    byts = parts * 8 * m * n * stack * (residue_shards - 1) / residue_shards
+    byts = parts * 4 * m * n * stack * (residue_shards - 1) / residue_shards
     return n_blocks * hw.collective_launch_s + byts / hw.ici_bw
 
 

@@ -708,20 +708,27 @@ def policy_matmul(x: jnp.ndarray, w, policy: GemmPolicy) -> jnp.ndarray:
         return y if policy.out_dtype is None else y.astype(policy.out_dtype)
     lead = x.shape[:-1]
     x2 = x.reshape((-1, x.shape[-1]))
-    if policy.is_adaptive:
-        # adaptive resolution happens *before* the custom-VJP boundary so the
-        # forward and both cotangent products run one concrete plan.  With
-        # concrete operands a cheap dynamic-range probe tightens the bound
-        # (possibly fewer moduli); under jit the probe returns None and the
-        # static worst case resolves — either way provably within rtol.
-        from .accuracy import probe_operands
-
-        policy = policy.resolve_adaptive(
-            x2.shape[0], x2.shape[1], w.shape[-1],
-            stats=probe_operands(x2, w),
-        )
-    y = emulated_matmul(x2, w, policy)
+    # adaptive resolution happens *before* the custom-VJP boundary so the
+    # forward and both cotangent products run one concrete plan
+    y = emulated_matmul(x2, w, resolve_for_operands(policy, x2, w))
     return y.reshape(lead + (w.shape[-1],))
+
+
+def resolve_for_operands(policy: GemmPolicy, x2, w) -> GemmPolicy:
+    """`policy` with its adaptive plan resolved for x2 (m, k) @ w (k, n).
+
+    With concrete operands a cheap dynamic-range probe tightens the bound
+    (possibly fewer moduli); under jit the probe returns None and the
+    static worst case resolves — either way provably within rtol.  A
+    policy that is not adaptive comes back as it is.
+    """
+    if not policy.is_adaptive:
+        return policy
+    from .accuracy import probe_operands
+
+    return policy.resolve_adaptive(
+        x2.shape[0], x2.shape[1], w.shape[-1], stats=probe_operands(x2, w)
+    )
 
 
 def prepare_weights(params, policy: GemmPolicy):

@@ -15,14 +15,14 @@ prefetch, so the compiled kernel is modulus-agnostic and takes the shard's
 dynamically-sliced chunk — and the only cross-device traffic of the whole
 pipeline is ONE psum per output block of the *reconstructed* output in its
 exact partial form (never the int8 planes, which at N moduli would be N
-bytes/element against `crt_partial_parts(N)` f64 words here but grow with
+bytes/element against `crt_partial_parts(N)` int32 words here but grow with
 every operand, not just the output).
 
 Exactness/bitwise contract (the falsifiable part): residue arithmetic is
 exact integers end to end, and the partial reconstruction is combined in
-the order-independent exact f64 split of `core/crt.partial_split` — each
+the order-independent exact int32 split of `core/crt.partial_split` — each
 device psums `sum_{l in chunk} u_{j,l} E_l` part-planes whose every partial
-sum is an exact integer below 2^53, then rebuilds the COMPLETE residue
+sum is an exact integer below 2^31, then rebuilds the COMPLETE residue
 planes locally (`crt.residues_from_partial`) and runs the ordinary Garner
 kernel on them.  The sharded output is therefore bitwise identical to the
 single-device kernel path on ANY mesh shape, not just numerically close;
@@ -35,9 +35,8 @@ per-shard maxima are combined with `lax.pmax` on int32 (exact) over the m/n
 mesh axes — wired through the executor's `accu_row_combine`/
 `accu_col_combine` backend hooks.
 
-The f64 partial planes are exact on CPU/GPU; a TPU deployment would carry
-them as two-f32 pairs (the same trick as the Garner kernel's double-single
-output) — noted in ROADMAP as the follow-up.
+The partial planes are int32, not f64: a TPU emulates f64 with less
+precision than the exact-integer window needs.
 """
 from __future__ import annotations
 
@@ -121,7 +120,7 @@ class _ShardWorker:
                 [np.asarray(ctx.moduli_arr), np.ones(n_pad - ctx.n, np.int32)]
             )
             u, _, _ = crt.partial_split(ctx.moduli)
-            u_pad = np.zeros((u.shape[0], n_pad), np.float64)
+            u_pad = np.zeros((u.shape[0], n_pad), np.int32)
             u_pad[:, : ctx.n] = u
             start = lax.axis_index(axes.residue) * self.chunk
             self.mod_loc = lax.dynamic_slice(
@@ -223,7 +222,7 @@ class _ShardWorker:
     def _full_planes(self, e_res, ctx, stacked: bool):
         """Local plane chunk -> COMPLETE (.., N, m, n) residue planes.
 
-        The single communication point: psum the exact f64 partial planes
+        The single communication point: psum the exact int32 partial planes
         over the residue axis (bitwise order-independent by construction),
         then rebuild all N residues locally.
         """
@@ -240,10 +239,10 @@ class _ShardWorker:
     # only cross-device traffic of the pipeline is one async-overlappable
     # collective instead of one serialized psum between consecutive blocks.
     # Bitwise identical to the per-block `_full_planes` route: a pytree
-    # psum is the same per-leaf reduction of exact f64 integer partials.
+    # psum is the same per-leaf reduction of exact int32 partials.
 
     def _psum_partial(self, e_res):
-        """Local (.., N_loc, m, n) plane chunk -> exact f64 partial planes
+        """Local (.., N_loc, m, n) plane chunk -> exact int32 partial planes
         (NO collective — collected by the executor across blocks)."""
         return crt.partial_combine(e_res, self.u_loc)
 
@@ -324,7 +323,7 @@ class ShardedBackend:
     def analyze(self, plan, shape=None):
         """Static-analysis suite certifying the sharded pipeline: the
         collective-safety pass is the load-bearing one here (only exact
-        f64 CRT partials may psum), and the launch-count certificate is
+        int32 CRT partials may psum), and the launch-count certificate is
         derived from `shard_factors` (the fused worker engages only on
         m/n-only meshes).  See repro.analysis.passes_for_backend."""
         from ..analysis import passes_for_backend

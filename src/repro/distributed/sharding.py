@@ -226,7 +226,7 @@ def residue_plane_specs(axes: GemmShardAxes) -> dict[str, P]:
 
     The spec table is the distributed design in one place: operands split
     rows/columns only, residue stacks additionally split the plane
-    dimension, the exact f64 partial-reconstruction planes are the ONLY
+    dimension, the exact int32 partial-reconstruction planes are the ONLY
     psum payload, and the reconstructed output is sharded like a normal
     GEMM result (no int8 array ever appears in a collective).
     """

@@ -64,6 +64,7 @@ from .core.policy import (
     emulated_matmul_planar,
     policy_matmul,
     prepare_weights,
+    resolve_for_operands,
     use_mesh,
 )
 
@@ -233,16 +234,21 @@ def _matmul_jit(x, w, *, policy):
 def matmul_jit(x, w, *, policy: GemmPolicy | None = None):
     """`matmul` behind a (shapes, policy)-cached `jax.jit` for eager callers.
 
-    The ambient policy — and, for a mesh-less sharded policy, the ambient
-    `use_mesh` mesh — is resolved *before* jit so the context scopes can
-    never leak stale into the compilation cache (a policy that resolved
-    mesh A at first trace must not silently serve mesh B's scope from the
-    cache).
+    The ambient policy and mesh are resolved *before* jit (`_pin_mesh`).
     """
     policy = current_policy() if policy is None else policy
-    if policy.execution == "sharded" and policy.mesh is None:
-        policy = dataclasses.replace(policy, mesh=current_mesh())
-    return _matmul_jit(x, w, policy=policy)
+    return _matmul_jit(x, w, policy=_pin_mesh(policy))
+
+
+def _pin_mesh(policy: GemmPolicy) -> GemmPolicy:
+    """`policy` with the ambient `use_mesh` mesh pinned where its execution
+    reads one (sharded; fused runs sharded inside a mesh scope).  A jitted
+    entry point resolves it before jit so the context scope can never leak
+    stale into the compilation cache (a policy that resolved mesh A at
+    first trace must not silently serve mesh B's scope from the cache)."""
+    if policy.mesh is None and policy.execution in ("sharded", "fused"):
+        return dataclasses.replace(policy, mesh=current_mesh())
+    return policy
 
 
 def _blas_policy(dtype, policy: GemmPolicy | None) -> GemmPolicy:
@@ -312,9 +318,10 @@ def zgemm(x, w, *, policy: GemmPolicy | None = None):
     A TPU program cannot take or return a c128 array, so concrete operands
     are split into planar f64 (re, im) parts on the host, the jitted
     `zgemm_planar` program runs on those, and the c128 result is assembled
-    on the host: it comes back as a NumPy array.  Inside a caller's `jit`
-    (traced operands) and for prepared weights the product takes the
-    complex route, which only the CPU compiles; call `zgemm_planar` there.
+    on the host: it comes back as a NumPy array on every backend, the CPU
+    included.  Inside a caller's `jit` (traced operands) and for prepared
+    weights the product takes the complex route, which only the CPU
+    compiles; call `zgemm_planar` there.
 
     >>> import jax.numpy as jnp, repro
     >>> a = jnp.eye(2, dtype=jnp.complex128) * 2j
@@ -326,21 +333,12 @@ def zgemm(x, w, *, policy: GemmPolicy | None = None):
         isinstance(v, jax.core.Tracer) for v in (x, w)
     ):
         return _blas("zgemm", jnp.complex128, x, w, policy)
-    pol = _blas_policy(jnp.complex128, policy)
+    pol = _pin_mesh(_blas_policy(jnp.complex128, policy))
     x = np.asarray(x, np.complex128)
     w = np.asarray(w, np.complex128)
-    if w.ndim == 2 and x.ndim >= 2 and pol.is_adaptive:
-        # what `policy_matmul` does for a 2D weight: probe the operands
-        from .core.accuracy import probe_operands
-
-        k = x.shape[-1]
-        pol = pol.resolve_adaptive(
-            x.size // k, k, w.shape[-1],
-            stats=probe_operands(x.reshape(-1, k), w),
-        )
-    if pol.mesh is None and pol.execution in ("sharded", "fused"):
-        # resolve the ambient mesh before jit, as `matmul_jit` does
-        pol = dataclasses.replace(pol, mesh=current_mesh())
+    if w.ndim == 2 and x.ndim >= 2:
+        # probe the c128 operands here: the planar program cannot
+        pol = resolve_for_operands(pol, x.reshape(-1, x.shape[-1]), w)
     cr, ci = _zgemm_planar_jit(x.real, x.imag, w.real, w.imag, policy=pol)
     cr, ci = np.asarray(cr), np.asarray(ci)
     out = np.empty(cr.shape, np.result_type(cr.dtype, np.complex64))
@@ -368,15 +366,14 @@ def zgemm_planar(xr, xi, wr, wi, *, policy: GemmPolicy | None = None):
             "zgemm_planar expects matrix operands (ndim >= 2); got shapes "
             f"{getattr(xr, 'shape', None)} @ {getattr(wr, 'shape', None)}"
         )
-    if pol.is_adaptive:
+    if pol.is_adaptive:  # static: the planar parts cannot be probed apart
         pol = pol.resolve_adaptive(xr.shape[-2], xr.shape[-1], wr.shape[-1])
-    if wr.ndim > 2 or xr.ndim == 2:
+    if wr.ndim > 2:
         return emulated_matmul_planar(xr, xi, wr, wi, pol)
     # (..., m, k) @ (k, n): fold the leading dims into rows, as policy_matmul
     lead = xr.shape[:-1]
-    k = xr.shape[-1]
     cr, ci = emulated_matmul_planar(
-        xr.reshape(-1, k), xi.reshape(-1, k), wr, wi, pol
+        *(v.reshape(-1, v.shape[-1]) for v in (xr, xi)), wr, wi, pol
     )
     return cr.reshape(lead + cr.shape[-1:]), ci.reshape(lead + ci.shape[-1:])
 

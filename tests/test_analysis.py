@@ -293,7 +293,7 @@ def test_partial_split_rejects_bad_tables():
     assert any("part_bits" in m for m in msgs)
     msgs = [f.message for f in certify_partial_split(
         moduli, u=np.array([[2.0**55]]), part_bits=60)]
-    assert any("2^53" in m for m in msgs)
+    assert any("2^31" in m for m in msgs)
 
 
 # ---------------------------------------------------------------------------

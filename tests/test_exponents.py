@@ -106,3 +106,26 @@ def test_helpers_lower_without_64bit_bitcasts(fn):
         arg = jax.ShapeDtypeStruct((8,), jnp.int32)
         text = jax.jit(exp2i).lower(arg).as_text()
     assert "bitcast_convert" not in text
+
+
+@pytest.mark.parametrize("routine", ["dgemm", "zgemm"])
+def test_inverse_scale_tiny_operands(rng, routine):
+    """Operands near 2^-500 on both sides: the inverse scaling power
+    2^-(e_mu + e_nu) lies below the f64 range, C does not.  Powers of two
+    pass through the pipeline exactly, so C is the unscaled product's C
+    times 2^-1000, bit for bit."""
+    from repro import linalg
+    from repro.core.policy import GemmPolicy
+
+    dtype = np.float64 if routine == "dgemm" else np.complex128
+    a = rng.uniform(-1, 1, (16, 24)).astype(dtype)
+    b = rng.uniform(-1, 1, (24, 8)).astype(dtype)
+    if routine == "zgemm":
+        a = a + 1j * rng.uniform(-1, 1, a.shape)
+        b = b + 1j * rng.uniform(-1, 1, b.shape)
+    fn = getattr(linalg, routine)
+    pol = GemmPolicy(mode="fast")
+    c = np.asarray(fn(a, b, policy=pol))
+    tiny = np.asarray(fn(a * 2.0**-500, b * 2.0**-500, policy=pol))
+    assert np.all(c != 0)
+    np.testing.assert_array_equal(tiny * 2.0**1000, c)

@@ -206,6 +206,16 @@ def test_acceptance_c64_kernel_drop_in(rng):
 # ===================================================== use_policy scoping
 
 
+def test_reference_execution_refuses_tpu(monkeypatch):
+    """The jnp reference reconstruction is exact only in IEEE f64, which a
+    TPU does not have: traced for a TPU it raises instead of rounding."""
+    a = jnp.ones((3, 5), jnp.float32)
+    b = jnp.ones((5, 7), jnp.float32)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(NotImplementedError, match="IEEE f64"):
+        linalg.sgemm(a, b, policy=GemmPolicy(execution="reference"))
+
+
 def test_use_policy_scoping():
     assert repro.current_policy() == GemmPolicy()
     p1 = GemmPolicy(backend="ozaki2_f32", n_moduli=6)

@@ -5,7 +5,7 @@ policy stack; this file covers its distribution):
 
   * sharded execution is **bitwise identical to execution="kernel"** — on a
     1-device mesh (the acceptance criterion) and, because the partial
-    reconstruction combines in the exact order-independent f64 split of
+    reconstruction combines in the exact order-independent int32 split of
     `core/crt.partial_split`, on EVERY mesh shape (data x model x residue),
     for {f32, f64, c64, c128} x {fast, accu} x all three complex
     formulations and under output-column blocking;
@@ -194,7 +194,7 @@ def test_sharded_reference_inner_bitwise(rng):
 
 def test_no_int8_crosses_the_mesh(rng):
     """The distribution contract: the ONLY communicated arrays are the
-    exact f64 partial-reconstruction planes (and int32 bound maxima in accu
+    exact int32 partial-reconstruction planes (and int32 bound maxima in accu
     mode) — never the int8 residue planes.  Certified by the shared
     `repro.analysis.CollectiveSafetyPass` (which the analysis CLI also runs
     on every matrix row in CI)."""
@@ -207,9 +207,9 @@ def test_no_int8_crosses_the_mesh(rng):
         assert findings == [], [str(f) for f in findings]
         colls = collect_collectives(jaxpr)
         assert colls, "sharded residue execution must communicate via psum"
-        # the payload is the exact f64 partial planes
+        # the payload is the exact int32 partial planes
         assert any(
-            name == "psum" and any(dt == jnp.float64 for dt in dtypes)
+            name == "psum" and any(dt == jnp.int32 for dt in dtypes)
             for name, dtypes in colls
         )
     # and the same invariant on the compiled (SPMD-partitioned) HLO: no
@@ -412,7 +412,7 @@ def test_sharded_plan_prices_communication():
     t8 = perfmodel.sharded_comm_time_s(256, 256, 8, 8)
     assert t8 > t2 > perfmodel.COLLECTIVE_LAUNCH_S
     parts = perfmodel.crt_partial_parts(8)
-    assert parts >= 2  # ~64-bit weights split into >= 2 exact f64 parts
+    assert parts >= 2  # ~64-bit weights split into >= 2 exact int32 parts
 
 
 # ======================================== parity: the fused megakernel
