@@ -1,0 +1,5 @@
+"""`assemble_ms` where the cell reports `call_ms_p95`, not `tflops`."""
+from bench.metrics.assemble_ms import read  # noqa: F401
+
+NAME, UNIT, BETTER, SOURCE = "assemble_ms.latency", "ms", "lower", "device_trace"
+LAYER, MOVES = "scaling and assembly", "call_ms_p95"

@@ -42,6 +42,32 @@ from .moduli import CRTContext, K_CHUNK_LIMIT, make_crt_context
 from .plan import EmulationPlan, make_plan, n_limbs_for_ctx
 from .residues import quantize, residues_from_quantized, sym_mod_int32
 
+#: The pipeline's stages.  Each runs under `stage(name)`, a
+#: `jax.named_scope("ozaki2.<name>")`, so the `op_name` metadata of the
+#: compiled program and a profile of it name the stage of every op:
+#:
+#:   scale     the scale exponents (`scaling.scale_*`, the accu bounds)
+#:   cast      the residue casts (`backend.cast`, `_cast_pair`)
+#:   product   the residue products, eq. 7/8 embeddings included
+#:   garner    CRT reconstruction and inverse scaling (`reconstruct*`)
+#:   assemble  the planar split, the complex output, n-block slices and
+#:             their concatenation
+#:   psum      the sharded partial combine (`psum_partial`, `psum_combine`)
+#:   fused     a megakernel launch (cast, products and Garner in one)
+#:
+#: The pads and slices a backend wraps around a kernel run inside the
+#: kernel's stage.  Where stages nest, the innermost names the op.  Scopes
+#: are trace-time metadata: the compiled program is the same but for names.
+STAGES = ("scale", "cast", "product", "garner", "assemble", "psum", "fused")
+
+
+def stage(name: str):
+    """The named scope of one pipeline stage (a context manager, or a
+    function decorator)."""
+    if name not in STAGES:
+        raise ValueError(f"unknown stage {name!r}; stages are {STAGES}")
+    return jax.named_scope(f"ozaki2.{name}")
+
 
 def _sym_mod_stack(d: jnp.ndarray, ctx: CRTContext) -> jnp.ndarray:
     outs = [sym_mod_int32(d[l], int(ctx.moduli_arr[l])) for l in range(ctx.n)]
@@ -106,6 +132,13 @@ def chunked_residue_matmul(
     return _sym_mod_stack(acc, ctx).astype(jnp.int8)
 
 
+@stage("cast")
+def _cast(backend, x, e, axis, ctx, n_limbs):
+    """Residue-cast one real operand."""
+    return backend.cast(x, e, axis, ctx, n_limbs)
+
+
+@stage("cast")
 def _cast_pair(backend, xr, xi, e, axis, ctx, n_limbs):
     """Residue-cast a real/imag pair sharing one scale vector.
 
@@ -123,6 +156,7 @@ def _cast_pair(backend, xr, xi, e, axis, ctx, n_limbs):
     return res[0], res[1]
 
 
+@stage("garner")
 def _reconstruct_pair(backend, er, ei, e_mu, e_nu, ctx, method, out_dtype):
     """Reconstruct a CR/CI residue pair (one stacked launch when the backend
     provides `reconstruct_stack`, else two `reconstruct` calls)."""
@@ -342,6 +376,7 @@ def _block_b(backend, arr, ari, brr, bri, ctx):
     return chat[:, :, n:], chat[:, :, :n]
 
 
+@stage("product")
 def _complex_product(backend, plan, arr, ari, brr, bri, ctx):
     if plan.formulation == "karatsuba":
         return backend.karatsuba(arr, ari, brr, bri, ctx)
@@ -382,29 +417,36 @@ def _blocked_pipeline_real(plan, backend, ctx, e_mu, ares, e_nu, bres_slice, n):
     psum_partial = getattr(backend, "psum_partial", None)
     slices = list(plan.n_block_slices(n))
     if psum_partial is not None:
-        partials = [
-            psum_partial(backend.residue_matmul(ares, bres_slice(sl), ctx))
-            for sl in slices
-        ]
-        planes = backend.psum_combine(partials)
-        blocks = [
-            backend.reconstruct_post(
-                e_r, e_mu, e_nu[sl], ctx, plan.method, plan.real_out_dtype
-            )
-            for e_r, sl in zip(planes, slices)
-        ]
-        return (
-            blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=1)
-        )
+        partials = []
+        for sl in slices:
+            with stage("assemble"):
+                bres = bres_slice(sl)
+            with stage("product"):
+                e_r = backend.residue_matmul(ares, bres, ctx)
+            with stage("psum"):
+                partials.append(psum_partial(e_r))
+        with stage("psum"):
+            planes = backend.psum_combine(partials)
+        blocks = []
+        for e_r, sl in zip(planes, slices):
+            with stage("assemble"):
+                e_nu_sl = e_nu[sl]
+            with stage("garner"):
+                blocks.append(backend.reconstruct_post(
+                    e_r, e_mu, e_nu_sl, ctx, plan.method, plan.real_out_dtype
+                ))
+        return _concat_cols(blocks)
     blocks = []
     for sl in slices:
-        e_r = backend.residue_matmul(ares, bres_slice(sl), ctx)
-        blocks.append(
-            backend.reconstruct(
-                e_r, e_mu, e_nu[sl], ctx, plan.method, plan.real_out_dtype
-            )
-        )
-    return blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=1)
+        with stage("assemble"):
+            bres, e_nu_sl = bres_slice(sl), e_nu[sl]
+        with stage("product"):
+            e_r = backend.residue_matmul(ares, bres, ctx)
+        with stage("garner"):
+            blocks.append(backend.reconstruct(
+                e_r, e_mu, e_nu_sl, ctx, plan.method, plan.real_out_dtype
+            ))
+    return _concat_cols(blocks)
 
 
 def _blocked_pipeline_complex(
@@ -420,34 +462,60 @@ def _blocked_pipeline_complex(
     if psum_partial is not None:
         partials = []
         for sl in slices:
-            brr, bri = bres_slice(sl)
+            with stage("assemble"):
+                brr, bri = bres_slice(sl)
             er, ei = _complex_product(backend, plan, arr, ari, brr, bri, ctx)
-            partials.append(psum_partial(jnp.stack([er, ei])))
-        planes = backend.psum_combine(partials, stacked=True)
+            with stage("psum"):
+                partials.append(psum_partial(jnp.stack([er, ei])))
+        with stage("psum"):
+            planes = backend.psum_combine(partials, stacked=True)
         blocks = []
         for full, sl in zip(planes, slices):
-            out = backend.reconstruct_post_stack(
-                full, e_mu, e_nu[sl], ctx, plan.method, rdt
-            )
-            blocks.append((out[0], out[1]))
+            with stage("assemble"):
+                e_nu_sl = e_nu[sl]
+            with stage("garner"):
+                out = backend.reconstruct_post_stack(
+                    full, e_mu, e_nu_sl, ctx, plan.method, rdt
+                )
+                blocks.append((out[0], out[1]))
         return _concat_planar(blocks)
     blocks = []
     for sl in slices:
-        brr, bri = bres_slice(sl)
+        with stage("assemble"):
+            (brr, bri), e_nu_sl = bres_slice(sl), e_nu[sl]
         er, ei = _complex_product(backend, plan, arr, ari, brr, bri, ctx)
         blocks.append(
             _reconstruct_pair(
-                backend, er, ei, e_mu, e_nu[sl], ctx, plan.method, rdt
+                backend, er, ei, e_mu, e_nu_sl, ctx, plan.method, rdt
             )
         )
     return _concat_planar(blocks)
 
 
+@stage("assemble")
+def _concat_cols(blocks):
+    """Output-column blocks -> one output."""
+    return blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=1)
+
+
+@stage("assemble")
 def _concat_planar(blocks):
     """[(cr, ci), ...] output-column blocks -> one planar (cr, ci) pair."""
     if len(blocks) == 1:
         return blocks[0]
     return tuple(jnp.concatenate(part, axis=1) for part in zip(*blocks))
+
+
+@stage("assemble")
+def _planar(x):
+    """A complex operand's (real, imag) parts."""
+    return jnp.real(x), jnp.imag(x)
+
+
+@stage("assemble")
+def _to_complex(planar):
+    """A planar (cr, ci) output as one complex array."""
+    return jax.lax.complex(*planar)
 
 
 # ------------------------------------------------------- fused megakernel
@@ -462,23 +530,31 @@ def _fused_pipeline_real(plan, backend, ctx, e_mu, a, e_nu, b_slice,
     blocks = []
     for sl in plan.n_block_slices(n):
         if b_res_slice is not None:
-            out = backend.fused_gemm(
-                a, None, e_mu, e_nu[sl], ctx, plan.n_limbs,
-                plan.real_out_dtype, b_res=b_res_slice(sl),
-            )
+            with stage("assemble"):
+                b_res, e_nu_sl = b_res_slice(sl), e_nu[sl]
+            with stage("fused"):
+                out = backend.fused_gemm(
+                    a, None, e_mu, e_nu_sl, ctx, plan.n_limbs,
+                    plan.real_out_dtype, b_res=b_res,
+                )
         else:
-            out = backend.fused_gemm(
-                a, b_slice(sl), e_mu, e_nu[sl], ctx, plan.n_limbs,
-                plan.real_out_dtype,
-            )
+            with stage("assemble"):
+                b_blk, e_nu_sl = b_slice(sl), e_nu[sl]
+            with stage("fused"):
+                out = backend.fused_gemm(
+                    a, b_blk, e_mu, e_nu_sl, ctx, plan.n_limbs,
+                    plan.real_out_dtype,
+                )
         blocks.append(out)
-    return blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=1)
+    return _concat_cols(blocks)
 
 
+@stage("fused")
 def _fused_complex_block(
     backend, plan, ctx, e_mu, ar, ai, e_nu_sl, b_blk, b_res_blk, nl, rdt
 ):
-    """One output-column block of the fused complex pipeline -> (cr, ci).
+    """One output-column block of the fused complex pipeline -> (cr, ci);
+    the block embeddings and the output's halves run in the fused stage.
 
     'karatsuba' runs the fused complex megakernel directly.  The block
     embeddings (paper eqs. 7/8) embed the RAW operands (or, prepared, the
@@ -555,11 +631,13 @@ def _fused_pipeline_complex(
     rdt = plan.real_out_dtype
     blocks = []
     for sl in plan.n_block_slices(n):
-        b_blk = None if b_res_slice is not None else b_slice(sl)
-        b_res_blk = b_res_slice(sl) if b_res_slice is not None else None
+        with stage("assemble"):
+            b_blk = None if b_res_slice is not None else b_slice(sl)
+            b_res_blk = b_res_slice(sl) if b_res_slice is not None else None
+            e_nu_sl = e_nu[sl]
         blocks.append(
             _fused_complex_block(
-                backend, plan, ctx, e_mu, ar, ai, e_nu[sl], b_blk, b_res_blk,
+                backend, plan, ctx, e_mu, ar, ai, e_nu_sl, b_blk, b_res_blk,
                 nl, rdt,
             )
         )
@@ -578,11 +656,12 @@ def _accu_combines(backend):
 
 def _execute_real(plan, a, b, backend):
     ctx = plan.ctx
-    if plan.mode == "fast":
-        e_mu, e_nu = scaling.scale_fast_real(a, b, ctx)
-    else:
-        rc, cc = _accu_combines(backend)
-        e_mu, e_nu = scaling.scale_accurate_real(a, b, ctx, rc, cc)
+    with stage("scale"):
+        if plan.mode == "fast":
+            e_mu, e_nu = scaling.scale_fast_real(a, b, ctx)
+        else:
+            rc, cc = _accu_combines(backend)
+            e_mu, e_nu = scaling.scale_accurate_real(a, b, ctx, rc, cc)
     nl = plan.n_limbs
     if getattr(backend, "megakernel", False):
         # fast AND accu mode: the scaling pass above is pallas-free, so the
@@ -591,19 +670,17 @@ def _execute_real(plan, a, b, backend):
             plan, backend, ctx, e_mu, a, e_nu,
             lambda sl: b[:, sl], None, b.shape[1],
         )
-    ares = backend.cast(a, e_mu, 0, ctx, nl)
+    ares = _cast(backend, a, e_mu, 0, ctx, nl)
     return _blocked_pipeline_real(
         plan, backend, ctx, e_mu, ares, e_nu,
-        lambda sl: backend.cast(b[:, sl], e_nu[sl], 1, ctx, nl),
+        lambda sl: _cast(backend, b[:, sl], e_nu[sl], 1, ctx, nl),
         b.shape[1],
     )
 
 
 def _execute_complex(plan, a, b, backend):
-    return jax.lax.complex(
-        *execute_plan_planar(
-            plan, jnp.real(a), jnp.imag(a), jnp.real(b), jnp.imag(b), backend
-        )
+    return _to_complex(
+        execute_plan_planar(plan, *_planar(a), *_planar(b), backend)
     )
 
 
@@ -612,11 +689,14 @@ def execute_plan_planar(plan: EmulationPlan, ar, ai, br, bi, backend=REFERENCE):
     (br + i bi) -> the planar (cr, ci) output.  No complex array is formed,
     so a c128 product never puts a c128 array on the device."""
     ctx = plan.ctx
-    if plan.mode == "fast":
-        e_mu, e_nu = scaling.scale_fast_complex(ar, ai, br, bi, ctx)
-    else:
-        rc, cc = _accu_combines(backend)
-        e_mu, e_nu = scaling.scale_accurate_complex(ar, ai, br, bi, ctx, rc, cc)
+    with stage("scale"):
+        if plan.mode == "fast":
+            e_mu, e_nu = scaling.scale_fast_complex(ar, ai, br, bi, ctx)
+        else:
+            rc, cc = _accu_combines(backend)
+            e_mu, e_nu = scaling.scale_accurate_complex(
+                ar, ai, br, bi, ctx, rc, cc
+            )
     nl = plan.n_limbs
     if getattr(backend, "megakernel", False):
         return _fused_pipeline_complex(
@@ -761,7 +841,7 @@ class PreparedOperand:
                     jnp.vectorize, signature=f"(m,k)->{evec},(l,m,k),(l,m,k)"
                 )
                 def _prep(x2):
-                    xr, xi = jnp.real(x2), jnp.imag(x2)
+                    xr, xi = _planar(x2)
                     e = _solo_scale_complex(xr, xi, ctx, side)
                     rr, ri = _cast_pair(backend, xr, xi, e, axis, ctx, nl)
                     return e, rr, ri
@@ -772,7 +852,7 @@ class PreparedOperand:
                 @functools.partial(jnp.vectorize, signature=sig)
                 def _prep(x2):
                     e = _solo_scale_real(x2, ctx, side)
-                    return e, backend.cast(x2, e, axis, ctx, nl)
+                    return e, _cast(backend, x2, e, axis, ctx, nl)
 
                 e_scale, *res = _prep(x)
 
@@ -781,6 +861,7 @@ class PreparedOperand:
         if keep_raw:
             if is_complex:
 
+                @stage("scale")
                 @functools.partial(
                     jnp.vectorize, signature=f"(m,k)->(m,k),(m,k),{evec}"
                 )
@@ -793,6 +874,7 @@ class PreparedOperand:
                 *bound, e_bound = _bound(x)
             else:
 
+                @stage("scale")
                 @functools.partial(
                     jnp.vectorize, signature=f"(m,k)->(m,k),{evec}"
                 )
@@ -876,6 +958,7 @@ jax.tree_util.register_pytree_node(
 )
 
 
+@stage("scale")
 def _solo_scale_real(x, ctx, side):
     """Fast-mode exponent of one operand alone (dummy other operand)."""
     if side == "left":
@@ -885,6 +968,7 @@ def _solo_scale_real(x, ctx, side):
     return e
 
 
+@stage("scale")
 def _solo_scale_complex(xr, xi, ctx, side):
     if side == "left":
         z = jnp.zeros((xr.shape[1], 1))
@@ -917,36 +1001,38 @@ def _gemm_prepared_accu(prep, x, plan, backend):
     other = "left" if prep.side == "right" else "right"
 
     if prep.is_complex:
-        xr, xi = jnp.real(x), jnp.imag(x)
-        xbar, e_xbar, x_nz = scaling.accu_bound_complex(xr, xi, other)
-        pbar, e_pbar = prep.bound, prep.e_bound
-        p_nz = jnp.max(
-            jnp.maximum(*[b.astype(jnp.int32) for b in pbar]),
-            axis=1 if prep.side == "left" else 0,
-        ) > 0
-        wr, wi = jnp.real(prep.raw), jnp.imag(prep.raw)
-        if prep.side == "left":
-            cmax = scaling.accu_cbar_complex(pbar, xbar)
-            e_mu, e_nu = scaling.accu_exponents(
-                cmax, e_pbar, e_xbar, p_nz, x_nz, ctx
-            )
-            ar_, ai_ = wr, wi
-            br_, bi_ = xr, xi
-        else:
-            cmax = scaling.accu_cbar_complex(xbar, pbar)
-            e_mu, e_nu = scaling.accu_exponents(
-                cmax, e_xbar, e_pbar, x_nz, p_nz, ctx
-            )
-            ar_, ai_ = xr, xi
-            br_, bi_ = wr, wi
+        xr, xi = _planar(x)
+        with stage("scale"):
+            xbar, e_xbar, x_nz = scaling.accu_bound_complex(xr, xi, other)
+            pbar, e_pbar = prep.bound, prep.e_bound
+            p_nz = jnp.max(
+                jnp.maximum(*[b.astype(jnp.int32) for b in pbar]),
+                axis=1 if prep.side == "left" else 0,
+            ) > 0
+        wr, wi = _planar(prep.raw)
+        with stage("scale"):
+            if prep.side == "left":
+                cmax = scaling.accu_cbar_complex(pbar, xbar)
+                e_mu, e_nu = scaling.accu_exponents(
+                    cmax, e_pbar, e_xbar, p_nz, x_nz, ctx
+                )
+                ar_, ai_ = wr, wi
+                br_, bi_ = xr, xi
+            else:
+                cmax = scaling.accu_cbar_complex(xbar, pbar)
+                e_mu, e_nu = scaling.accu_exponents(
+                    cmax, e_xbar, e_pbar, x_nz, p_nz, ctx
+                )
+                ar_, ai_ = xr, xi
+                br_, bi_ = wr, wi
         if getattr(backend, "megakernel", False):
             # accu re-casts from raw anyway, so the fused prologue applies
-            return jax.lax.complex(*_fused_pipeline_complex(
+            return _to_complex(_fused_pipeline_complex(
                 plan, backend, ctx, e_mu, ar_, ai_, e_nu,
                 lambda sl: (br_[:, sl], bi_[:, sl]), None, br_.shape[1],
             ))
         arr, ari = _cast_pair(backend, ar_, ai_, e_mu, 0, ctx, nl)
-        return jax.lax.complex(*_blocked_pipeline_complex(
+        return _to_complex(_blocked_pipeline_complex(
             plan, backend, ctx, e_mu, arr, ari, e_nu,
             lambda sl: _cast_pair(
                 backend, br_[:, sl], bi_[:, sl], e_nu[sl], 1, ctx, nl
@@ -954,32 +1040,33 @@ def _gemm_prepared_accu(prep, x, plan, backend):
             br_.shape[1],
         ))
 
-    xbar, e_xbar, x_nz = scaling.accu_bound_real(x, other)
-    pbar, e_pbar = prep.bound[0], prep.e_bound
-    p_nz = jnp.max(
-        pbar.astype(jnp.int32), axis=1 if prep.side == "left" else 0
-    ) > 0
-    if prep.side == "left":
-        cbar = int8_matmul(pbar, xbar)
-        e_mu, e_nu = scaling.accu_exponents(
-            cbar, e_pbar, e_xbar, p_nz, x_nz, ctx
-        )
-        a_, b_ = prep.raw, x
-    else:
-        cbar = int8_matmul(xbar, pbar)
-        e_mu, e_nu = scaling.accu_exponents(
-            cbar, e_xbar, e_pbar, x_nz, p_nz, ctx
-        )
-        a_, b_ = x, prep.raw
+    with stage("scale"):
+        xbar, e_xbar, x_nz = scaling.accu_bound_real(x, other)
+        pbar, e_pbar = prep.bound[0], prep.e_bound
+        p_nz = jnp.max(
+            pbar.astype(jnp.int32), axis=1 if prep.side == "left" else 0
+        ) > 0
+        if prep.side == "left":
+            cbar = int8_matmul(pbar, xbar)
+            e_mu, e_nu = scaling.accu_exponents(
+                cbar, e_pbar, e_xbar, p_nz, x_nz, ctx
+            )
+            a_, b_ = prep.raw, x
+        else:
+            cbar = int8_matmul(xbar, pbar)
+            e_mu, e_nu = scaling.accu_exponents(
+                cbar, e_xbar, e_pbar, x_nz, p_nz, ctx
+            )
+            a_, b_ = x, prep.raw
     if getattr(backend, "megakernel", False):
         return _fused_pipeline_real(
             plan, backend, ctx, e_mu, a_, e_nu,
             lambda sl: b_[:, sl], None, b_.shape[1],
         )
-    ares = backend.cast(a_, e_mu, 0, ctx, nl)
+    ares = _cast(backend, a_, e_mu, 0, ctx, nl)
     return _blocked_pipeline_real(
         plan, backend, ctx, e_mu, ares, e_nu,
-        lambda sl: backend.cast(b_[:, sl], e_nu[sl], 1, ctx, nl),
+        lambda sl: _cast(backend, b_[:, sl], e_nu[sl], 1, ctx, nl),
         b_.shape[1],
     )
 
@@ -1062,7 +1149,7 @@ def gemm_prepared(
     fused = getattr(backend, "megakernel", False) and prep.side == "right"
 
     if prep.is_complex:
-        xr, xi = jnp.real(x), jnp.imag(x)
+        xr, xi = _planar(x)
         e_other = _solo_scale_complex(xr, xi, ctx, other_side)
         if prep.side == "left":
             e_mu, e_nu = prep.e_scale, e_other
@@ -1073,7 +1160,7 @@ def gemm_prepared(
         else:
             e_mu, e_nu = e_other, prep.e_scale
             if fused:
-                return jax.lax.complex(*_fused_pipeline_complex(
+                return _to_complex(_fused_pipeline_complex(
                     plan, backend, ctx, e_mu, xr, xi, e_nu, None,
                     lambda sl: tuple(r[..., sl] for r in prep.residues), n,
                 ))
@@ -1081,15 +1168,15 @@ def gemm_prepared(
             bres_slice = lambda sl: tuple(  # noqa: E731
                 r[..., sl] for r in prep.residues
             )
-        return jax.lax.complex(*_blocked_pipeline_complex(
+        return _to_complex(_blocked_pipeline_complex(
             plan, backend, ctx, e_mu, arr, ari, e_nu, bres_slice, n
         ))
 
     e_other = _solo_scale_real(x, ctx, other_side)
     if prep.side == "left":
         e_mu, e_nu, ares = prep.e_scale, e_other, prep.res
-        bres_slice = lambda sl: backend.cast(  # noqa: E731
-            x[:, sl], e_nu[sl], 1, ctx, nl
+        bres_slice = lambda sl: _cast(  # noqa: E731
+            backend, x[:, sl], e_nu[sl], 1, ctx, nl
         )
     else:
         e_mu, e_nu = e_other, prep.e_scale
@@ -1098,7 +1185,7 @@ def gemm_prepared(
                 plan, backend, ctx, e_mu, x, e_nu, None,
                 lambda sl: prep.res[..., sl], n,
             )
-        ares = backend.cast(x, e_mu, 0, ctx, nl)
+        ares = _cast(backend, x, e_mu, 0, ctx, nl)
         bres_slice = lambda sl: prep.res[..., sl]  # noqa: E731
     return _blocked_pipeline_real(
         plan, backend, ctx, e_mu, ares, e_nu, bres_slice, n

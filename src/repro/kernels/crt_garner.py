@@ -136,6 +136,7 @@ def _stacked_call(e_res, r1, r2, c1, c2, *, ctx, out_dd, bm, bn, interpret):
         out_specs=out_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="crt_garner",
     )(e_res, r1[:, None], r2[:, None], c1[None, :], c2[None, :])
 
 

@@ -106,5 +106,6 @@ def flash_attention(
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qr, kr, vr)
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)

@@ -165,6 +165,7 @@ def _batched_call(a, b, carry, mod_arr, *, bm, bn, bk, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_mod, m, n), jnp.int8),
         interpret=interpret,
+        name="fp8_mod_gemm",
     )(mod_arr, *operands)
 
 
@@ -265,6 +266,7 @@ def _karatsuba_call(ar, ai, br, bi, carry, mod_arr, *, bm, bn, bk, interpret):
             jax.ShapeDtypeStruct((n_mod, m, n), jnp.int8),
         ),
         interpret=interpret,
+        name="fp8_karatsuba_mod_gemm",
     )(mod_arr, *operands)
 
 

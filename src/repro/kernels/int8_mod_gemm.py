@@ -109,6 +109,7 @@ def _batched_call(a, b, carry, mod_arr, *, bm, bn, bk, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_mod, m, n), jnp.int8),
         interpret=interpret,
+        name="int8_mod_gemm",
     )(mod_arr, *operands)
 
 
@@ -292,6 +293,7 @@ def _fused_call(
         scratch_shapes=[pltpu.VMEM((ctx.n, bm, bn), jnp.int32)],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=FUSED_VMEM_LIMIT),
         interpret=interpret,
+        name="fused_mod_gemm",
     )(*operands)
 
 

@@ -135,6 +135,7 @@ def _batched_call(ar, ai, br, bi, carry, mod_arr, *, bm, bn, bk, interpret):
             jax.ShapeDtypeStruct((n_mod, m, n), jnp.int8),
         ),
         interpret=interpret,
+        name="karatsuba_mod_gemm",
     )(mod_arr, *operands)
 
 
@@ -334,6 +335,7 @@ def _fused_call(
         ],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=FUSED_VMEM_LIMIT),
         interpret=interpret,
+        name="fused_karatsuba_mod_gemm",
     )(*operands)
 
 

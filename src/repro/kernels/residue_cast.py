@@ -70,6 +70,7 @@ def _stacked_call(a, scale1, scale2, *, moduli, n_limbs, scale_axis, bm, bk,
         out_specs=pl.BlockSpec((1, n, bm, bk), lambda si, i, j: (si, I0, i, j)),
         out_shape=jax.ShapeDtypeStruct((s, n, m, k), jnp.int8),
         interpret=interpret,
+        name="residue_cast",
     )(a, scale1, scale2)
 
 

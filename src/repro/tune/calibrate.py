@@ -160,6 +160,7 @@ def _measure_gemm_launch_s() -> float:
             _copy,
             out_shape=jax.ShapeDtypeStruct(v.shape, v.dtype),
             interpret=interpret_default(),
+            name="launch_probe",
         )(v)
     )
     return _time_s(f, x)

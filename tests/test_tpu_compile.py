@@ -10,9 +10,16 @@ blocks, and check that each program holds a compiled Mosaic kernel
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU compiler library.
+
+The cgemm program is also checked for the names a profile shows: each
+Mosaic kernel by its `pallas_call` name, each op by the `ozaki2.<stage>`
+scope of the executor's stage that made it.  The scopes are metadata only;
+a CPU case pins the output bits the program gave before it had them.
 """
 import functools
+import hashlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +27,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import linalg
+from repro.core.executor import STAGES
 from repro.core.moduli import make_crt_context
 from repro.core.plan import default_n_moduli, n_limbs_for_ctx
 from repro.core.policy import GemmPolicy
@@ -150,3 +158,89 @@ def test_linalg_program_compiles_for_v5e(one_chip, routine):
         fn = functools.partial(linalg.zgemm_planar, policy=pol)
         shapes = (x,) * 4
     assert "tpu_custom_call" in _compile_text(fn, *shapes)
+
+
+#: the Mosaic kernels of jitted `linalg.cgemm` on the kernel path (fast,
+#: Karatsuba, one n-block), by `pallas_call` name: both operands' casts,
+#: the product and the Garner reconstruction
+CGEMM_KERNELS = {"residue_cast": 2, "karatsuba_mod_gemm": 1, "crt_garner": 1}
+#: opcodes that compute nothing of their own
+NO_WORK = {"parameter", "constant", "bitcast", "tuple", "get-tuple-element"}
+_LINE = re.compile(r"^\s*(?:ROOT\s+)?(%[\w.\-]+) = .*? ([\w\-]+)\((.*?)\)")
+
+
+def _compiler_made(line: str, params: dict) -> bool:
+    """Ops the TPU compiler makes with no source in the program, so no
+    scope: the float32 halves of a complex64 argument (`X64SplitLow` /
+    `X64SplitHigh` of the parameter, named after the argument), and the
+    asynchronous copy of a constant into a kernel's memory space."""
+    m = _LINE.match(line)
+    op_name = re.search(r'op_name="([^"]*)"', line)
+    if 'custom_call_target="X64Split' in line:
+        return m.group(3) in params and op_name and op_name.group(1) == params[m.group(3)]
+    return m.group(2) in ("copy-start", "copy-done") and op_name is None
+
+
+def test_cgemm_program_names_its_kernels_and_stages(one_chip):
+    """Jitted `linalg.cgemm` compiled for the v5e at 1024: every Mosaic
+    kernel is found by its `pallas_call` name (instruction name and
+    `op_name`), and every op that computes carries an `ozaki2.<stage>`
+    scope, but the ones the compiler makes with no source in a stage."""
+    pol = GemmPolicy(execution="kernel", interpret=False)
+    x = _spec(one_chip, (1024, 1024), jnp.complex64)
+    text = _compile_text(functools.partial(linalg.cgemm, policy=pol), x, x)
+    lines = text[text.index("\nENTRY "):].splitlines()[1:]
+    entry = [line for line in lines[: lines.index("}")] if _LINE.match(line)]
+    params = {}
+    for line in entry:
+        name, opcode, _ = _LINE.match(line).groups()
+        if opcode == "parameter":
+            params[name] = re.search(r'op_name="([^"]*)"', line).group(1)
+    kernels, unscoped = {}, []
+    for line in entry:
+        name, opcode, _ = _LINE.match(line).groups()
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        op_name = op_name.group(1) if op_name else ""
+        if 'custom_call_target="tpu_custom_call"' in line:
+            kernel = re.search(r"/(\w+)/pallas_call$", op_name).group(1)
+            assert re.fullmatch(rf"%{kernel}(\.\d+)?", name), (name, op_name)
+            kernels[kernel] = kernels.get(kernel, 0) + 1
+        scopes = re.findall(r"ozaki2\.(\w+)", op_name)
+        assert set(scopes) <= set(STAGES), op_name
+        if opcode not in NO_WORK and not scopes:
+            unscoped.append(line)
+    assert kernels == CGEMM_KERNELS
+    assert unscoped and all(_compiler_made(line, params) for line in unscoped), [
+        line[:200] for line in unscoped if not _compiler_made(line, params)]
+
+
+#: sha256 of the complex64 output bytes of `linalg.cgemm` on the CPU for the
+#: operands of `_parity_operands`, as the program gave them before its
+#: stages were scoped; all three executions agree to the bit
+CGEMM_64x64x256_SHA256 = (
+    "fd83c0b6a38058eb89037ce6ee3e4c23e055595749c7eeb35f9fd068a941ed97"
+)
+
+
+def _parity_operands():
+    import numpy as np
+
+    rng = np.random.default_rng(20261018)
+
+    def draw(shape):
+        return (rng.standard_normal(shape) * np.exp2(rng.integers(-8, 8, shape))
+                + 1j * rng.standard_normal(shape)).astype(np.complex64)
+
+    return draw((64, 256)), draw((256, 64))
+
+
+@pytest.mark.parametrize("execution", ["kernel", "fused", "reference"])
+def test_stage_scopes_change_no_output_bit(execution):
+    """m = n = 64, k = 256 on the CPU (Pallas in interpret mode): the
+    output's bits are the ones pinned before the scopes existed."""
+    import numpy as np
+
+    a, b = _parity_operands()
+    y = np.asarray(linalg.cgemm(a, b, policy=GemmPolicy(execution=execution)))
+    assert y.dtype == np.complex64 and y.shape == (64, 64)
+    assert hashlib.sha256(y.tobytes()).hexdigest() == CGEMM_64x64x256_SHA256
