@@ -223,9 +223,57 @@ def block_and_padded(
     return b, round_up(dim, b)
 
 
-#: static default (bm, bn, bk) of every batched/fused GEMM kernel — what
-#: runs when no calibration is active and the caller passes no blocks
+#: static default (bm, bn, bk) of the batched/fused GEMM kernels — what
+#: runs when no calibration is active and the caller passes no blocks —
+#: but the complex residue product's, which `product_tile` picks
 DEFAULT_GEMM_BLOCKS = (256, 256, 512)
+
+#: the v5e compiler's scoped-VMEM limit when a kernel sets none
+DEFAULT_SCOPED_VMEM = 16 << 20
+#: VMEM the complex residue product's tile may fill (a v5e core has 128 MiB)
+PRODUCT_VMEM_BUDGET = 96 << 20
+#: the complex residue product's largest tile: the fastest of PERF.md's
+#: sweep on a v5e at m = n = k = 8192 (119.9 ms a call, against 245.4 at
+#: the static blocks)
+PRODUCT_TILE = (1024, 1024, 1024)
+
+
+def product_vmem_bytes(bm: int, bn: int, bk: int) -> int:
+    """Scoped VMEM of `karatsuba_mod_gemm_batched` at one tile.
+
+    Per input element 8 bytes: four int8 tiles double-buffered (2), and the
+    f32 temporaries of the (AR+AI) and (BR+BI) sum tiles (6).  Per output
+    element 52: the two int8 output tiles and the two carry tiles
+    double-buffered (8), three int32 accumulators (12), the three int32
+    dot results (12) and the epilogue's f32 reductions (20).  The costs
+    are fitted to the least limit at which the v5e compiler takes each
+    tile of PERF.md's sweep at m = n = k = 8192 with no carry: the sum is
+    at most 2 MiB under it, and `_batched_call` keeps 4 MiB to spare.
+    """
+    return 8 * (bm + bn) * bk + 52 * bm * bn
+
+
+def product_tile(m: int, n: int, k: int) -> tuple[int, int, int]:
+    """The default tile of the complex residue product at (m, n, k).
+
+    `PRODUCT_TILE`, with bm and bn cut to a quarter of their axis in
+    multiples of 128 (but not below the static 256), then shrunk to the
+    axes as the kernel runs it (`block_and_padded`); the tile returned is
+    that shrunk one, and its footprint fits `PRODUCT_VMEM_BUDGET`.  Larger
+    tiles re-read each int8 tile, rebuild each Karatsuba sum tile and step
+    the grid fewer times per MXU operation.  But the kernel's code grows
+    with bm * bn and the program keeps it in HBM (on a v5e 4.8 MB more at
+    1024 x 1024 than at 256 x 256, 14% of what a jitted 1024-wide cgemm
+    holds), so the output keeps at least 16 tiles.  Only this kernel takes
+    the tile: the real product keeps `DEFAULT_GEMM_BLOCKS` (no benchmark
+    cell runs it), as do the fused megakernels, whose N x 3 accumulators
+    outgrow VMEM at these tiles, and the fp8 kernels, whose budgets differ.
+    """
+    bm, bn, bk = PRODUCT_TILE
+    bm = max(256, min(bm, m // 512 * 128))
+    bn = max(256, min(bn, n // 512 * 128))
+    return tuple(block_and_padded(dim, b, align=128)[0]
+                 for dim, b in ((m, bm), (n, bn), (k, bk)))
 
 
 def resolve_blocks(
@@ -244,7 +292,8 @@ def resolve_blocks(
     Explicit caller-passed values always win per axis.  Unset axes resolve
     from the active calibration's autotuned winner for this slot
     (`repro.tune` — `current_calibration().block_for(block_key(...))`),
-    else the static `DEFAULT_GEMM_BLOCKS`.  The result then flows through
+    else `product_tile(m, n, k)` for the ("kernel", "complex") slot and the
+    static `DEFAULT_GEMM_BLOCKS` for every other.  The result then flows through
     the exact same `block_and_padded` pad-and-slice path as the defaults,
     so tuned blocks can never change numerics — only which tiles the
     `pallas_call` grid steps over.
@@ -257,7 +306,12 @@ def resolve_blocks(
         cal = current_calibration()
         if cal is not None:
             tuned = cal.block_for(block_key(family, dclass, m, n, k))
-    base = tuned or DEFAULT_GEMM_BLOCKS
+    if tuned:
+        base = tuned
+    elif (family, dclass) == ("kernel", "complex"):
+        base = product_tile(m, n, k)
+    else:
+        base = DEFAULT_GEMM_BLOCKS
     return (
         bm if bm is not None else base[0],
         bn if bn is not None else base[1],

@@ -37,12 +37,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .common import (
+    DEFAULT_SCOPED_VMEM,
     FUSED_VMEM_LIMIT,
     I0,
     block_and_padded,
     dyn_mod_params,
     interpret_default,
     pad_dims,
+    product_vmem_bytes,
     residue_tiles_f32,
     resolve_blocks,
     split_scale_exponent,
@@ -134,6 +136,8 @@ def _batched_call(ar, ai, br, bi, carry, mod_arr, *, bm, bn, bk, interpret):
             jax.ShapeDtypeStruct((n_mod, m, n), jnp.int8),
             jax.ShapeDtypeStruct((n_mod, m, n), jnp.int8),
         ),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=max(
+            DEFAULT_SCOPED_VMEM, product_vmem_bytes(bm, bn, bk) + (4 << 20))),
         interpret=interpret,
         name="karatsuba_mod_gemm",
     )(mod_arr, *operands)

@@ -10,9 +10,11 @@ Three facts make this safe and cheap:
 * every kernel pads-and-slices (`kernels/common.block_and_padded`), so the
   block shape can never change numerics — the autotuner only ever trades
   speed, which is why the winners need no accuracy re-validation;
-* the static default ``(256, 256, 512)`` is always in the candidate set, so
-  a tuned configuration is never *measured* slower than the default at tune
-  time — throughput can only hold or improve;
+* each slot's default is always in its candidate set — the static
+  ``(256, 256, 512)``, and for the ("kernel", "complex") slot also the
+  tile `kernels.common.product_tile` picks for the shape — so a tuned
+  configuration is never *measured* slower than the default at tune time;
+  throughput can only hold or improve;
 * candidates are MXU-aligned multiples (bm/bn of 128, bk of at least 128),
   and they flow through the same `block_and_padded` selection the defaults
   do, so a tuned block larger than a dim still shrinks exactly like the
@@ -163,10 +165,13 @@ def autotune_blocks(
 ) -> dict:
     """Sweep the candidate grid; returns {block_key: (bm, bn, bk)} winners.
 
-    The static default triple is force-included in `candidates`, so the
-    recorded winner for every slot is measured at least as fast as the
-    default at tune time.
+    The static default triple is force-included in `candidates`, and the
+    ("kernel", "complex") slots' default tile in theirs, so the recorded
+    winner for every slot is measured at least as fast as the default at
+    tune time.
     """
+    from ..kernels.common import product_tile
+
     shapes = shapes or (_SHAPES_SMOKE if smoke else _SHAPES_FULL)
     candidates = tuple(candidates or
                        (_CANDIDATES_SMOKE if smoke else _CANDIDATES_FULL))
@@ -178,8 +183,14 @@ def autotune_blocks(
         for dclass in dclasses:
             for m, n, k in shapes:
                 entry = _make_entry(family, dclass, m, n, k, n_moduli)
+                slot = candidates
+                if (family, dclass) == ("kernel", "complex"):
+                    # this slot only: the fused kernels overflow VMEM at it
+                    tile = product_tile(m, n, k)
+                    if tile not in candidates:
+                        slot = (tile,) + candidates
                 best, best_t = None, float("inf")
-                for bm, bn, bk in candidates:
+                for bm, bn, bk in slot:
                     t = _median_time_s(entry(bm, bn, bk), iters)
                     if verbose:
                         print(

@@ -246,6 +246,41 @@ def test_karatsuba_ragged_regression(rng):
     np.testing.assert_array_equal(np.asarray(ci), np.asarray(ei))
 
 
+def test_karatsuba_default_tile_matches_the_static_blocks(rng):
+    """The shape-and-VMEM default tile of the complex residue product
+    against the explicit static (256, 256, 512) blocks, at a shape with a
+    ragged K, where the two run different tiles ((384, 384, 384) against
+    (256, 256, 384)) and each spans several on every axis: the residues
+    are bitwise equal."""
+    from repro.kernels.common import (
+        DEFAULT_GEMM_BLOCKS,
+        block_and_padded,
+        resolve_blocks,
+    )
+
+    m = n = 1536
+    k = 1100
+    ctx = make_crt_context(3)
+    runs = [
+        [block_and_padded(d, b, align=128)[0] for d, b in zip((m, n, k), blocks)]
+        for blocks in (resolve_blocks("kernel", "complex", m, n, k), DEFAULT_GEMM_BLOCKS)
+    ]
+    assert runs == [[384, 384, 384], [256, 256, 384]]
+    mats = [
+        jnp.asarray(np.stack([
+            rng.integers(-(p // 2), p // 2 + 1, size=s).astype(np.int8)
+            for p in ctx.moduli
+        ]))
+        for s in [(m, k), (m, k), (k, n), (k, n)]
+    ]
+    got = karatsuba_mod_gemm_batched(*mats, moduli=ctx.moduli)
+    bm, bn, bk = DEFAULT_GEMM_BLOCKS
+    want = karatsuba_mod_gemm_batched(*mats, moduli=ctx.moduli,
+                                      bm=bm, bn=bn, bk=bk)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 def test_full_pipeline_ragged_default_blocks(rng):
     """m=257 exceeds the default 256-row block and is not divisible by it —
     exactly the case that raised before pad-and-slice; the padded pipeline

@@ -5,8 +5,10 @@ compiler refuses: s64 block indices, 1-D blocks that do not match the TPU
 layout, VMEM overruns, f64 bit tricks the TPU's x64 rewriter cannot lower,
 c128 program operands.  These tests compile for a described `v5e:2x2`
 topology (no chip attached; nothing runs) at 4096 widths with the default
-blocks, and check that each program holds a compiled Mosaic kernel
-(`tpu_custom_call`), i.e. that nothing fell back to interpret mode.
+blocks (the complex residue product also at the benchmark's 8192 and 1024,
+each at its default tile), and check that each program holds a compiled
+Mosaic kernel (`tpu_custom_call`), i.e. that nothing fell back to
+interpret mode.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU compiler library.
@@ -92,11 +94,13 @@ def _kernel_case(name, sh):
             int8_mod_gemm_batched, moduli=ctx_r.moduli, interpret=False
         )
         return fn, (i8(N_REAL, W, k), i8(N_REAL, k, W))
-    if name == "karatsuba_mod_gemm_batched":
+    if name.startswith("karatsuba_mod_gemm_batched"):
+        # the cells' widths beside W, each at its default tile
+        size = int(name.split("_")[-1]) if name[-1].isdigit() else W
         fn = functools.partial(
             karatsuba_mod_gemm_batched, moduli=ctx_c.moduli, interpret=False
         )
-        return fn, (i8(N_COMPLEX, W, W),) * 4
+        return fn, (i8(N_COMPLEX, size, size),) * 4
     if name == "fused_mod_gemm":
         fn = lambda a, b, em, en: fused_mod_gemm(  # noqa: E731
             a, b, em, en, ctx_r, n_limbs=n_limbs_for_ctx(ctx_r),
@@ -131,6 +135,8 @@ def _kernel_case(name, sh):
         "int8_mod_gemm_batched",
         "int8_mod_gemm_batched_ragged_k",
         "karatsuba_mod_gemm_batched",
+        "karatsuba_mod_gemm_batched_8192",
+        "karatsuba_mod_gemm_batched_1024",
         "fused_mod_gemm",
         "fused_karatsuba_mod_gemm",
         "residue_cast_axis0",

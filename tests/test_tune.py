@@ -7,7 +7,8 @@ The contract under test (ISSUE 9 / docs/calibration.md):
   default blocks;
 * with no calibration present, behaviour is bitwise identical to the
   pre-calibration code — presets price every 'auto' decision and the
-  kernels launch the static default blocks;
+  kernels launch their default blocks (the static ones, but the complex
+  residue product's shape-and-VMEM tile);
 * with a calibration active, the measured `HW` drives the 'auto'
   selections deterministically and the kernels launch the tuned blocks —
   which can never change numerics (pad-and-slice), only speed;
@@ -240,6 +241,73 @@ def test_resolve_blocks_reads_tuned_and_respects_overrides():
             DEFAULT_GEMM_BLOCKS
     assert resolve_blocks("kernel", "real", 300, 300, 300) == \
         DEFAULT_GEMM_BLOCKS
+
+
+@pytest.mark.parametrize("size,tile,steps", [
+    (8192, (1024, 1024, 1024), 7 * 8 * 8 * 8),
+    (1024, (256, 256, 1024), 7 * 4 * 4),
+    (300, (128, 128, 300), 7 * 3 * 3),
+])
+def test_resolve_blocks_complex_kernel_takes_the_product_tile(size, tile, steps):
+    """No calibration, no explicit block: the complex residue product runs
+    the tile of `product_tile` (bm, bn at most a quarter of their axis),
+    shrunk to a small or ragged axis, at the grid PERF.md section 4
+    records, inside its VMEM budget."""
+    from repro.kernels.common import (
+        PRODUCT_VMEM_BUDGET,
+        block_and_padded,
+        product_vmem_bytes,
+    )
+
+    got = resolve_blocks("kernel", "complex", size, size, size)
+    assert got == tile
+    padded = [block_and_padded(size, b, align=128) for b in got]
+    assert [b for b, _ in padded] == list(tile)  # the kernel runs it as is
+    assert 7 * np.prod([p // b for b, p in padded]) == steps
+    assert product_vmem_bytes(*tile) <= PRODUCT_VMEM_BUDGET
+
+
+@pytest.mark.parametrize("family,dclass", [
+    ("kernel", "real"), ("fused", "real"), ("fused", "complex"),
+    ("fp8", "real"), ("fp8", "complex"),
+])
+@pytest.mark.parametrize("size", [8192, 1024])
+def test_resolve_blocks_other_kernels_keep_the_static_default(family, dclass, size):
+    assert resolve_blocks(family, dclass, size, size, size) == \
+        DEFAULT_GEMM_BLOCKS
+
+
+def test_resolve_blocks_complex_kernel_overrides_beat_the_product_tile():
+    """Explicit axes and an active calibration still win over the rule."""
+    assert resolve_blocks("kernel", "complex", 8192, 8192, 8192, bm=256) == \
+        (256, 1024, 1024)
+    assert resolve_blocks(
+        "kernel", "complex", 8192, 8192, 8192, bm=256, bn=256, bk=512
+    ) == DEFAULT_GEMM_BLOCKS
+    cal = make_cal({block_key("kernel", "complex", 8192, 8192, 8192):
+                    (128, 128, 256)})
+    with use_calibration(cal):
+        assert resolve_blocks("kernel", "complex", 8192, 8192, 8192) == \
+            (128, 128, 256)
+        assert resolve_blocks("kernel", "complex", 8192, 8192, 8192,
+                              bk=512) == (128, 128, 512)
+
+
+@pytest.mark.parametrize("family,dclass", [("kernel", "complex"), ("fused", "complex")])
+def test_autotune_times_each_slot_at_its_default(monkeypatch, family, dclass):
+    """The autotuner's candidates hold the slot's default: the product
+    tile for the complex residue product only, the static one for all."""
+    from repro.kernels.common import product_tile
+    from repro.tune import autotune
+
+    timed = []
+    monkeypatch.setattr(autotune, "_make_entry", lambda *a: lambda *tile: tile)
+    monkeypatch.setattr(autotune, "_median_time_s",
+                        lambda tile, iters: timed.append(tile) or 1.0)
+    shape = (2048, 2048, 2048)
+    autotune.autotune_blocks(families=(family,), dclasses=(dclass,), shapes=(shape,))
+    assert DEFAULT_GEMM_BLOCKS in timed
+    assert (product_tile(*shape) in timed) == (family == "kernel")
 
 
 def test_no_calibration_kernel_blocks_are_the_static_defaults(rng):
